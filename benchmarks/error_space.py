@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import argparse
 import math
+import traceback
 from typing import Dict, List
 
 import numpy as np
@@ -18,6 +19,15 @@ from benchmarks.common import WindowOracle, eval_queries, run_sketch, \
     write_csv
 from repro.data.streams import get_stream
 from repro.sketch.api import available_sketches
+
+
+class SweepError(RuntimeError):
+    """Some cells of a sweep failed; ``rows`` holds the cells that ran."""
+
+    def __init__(self, failures: List[str], rows: List[Dict]):
+        super().__init__(f"{len(failures)} sweep cell(s) failed: "
+                         + "; ".join(failures))
+        self.failures, self.rows = failures, rows
 
 
 def sweep(dataset: str, *, scale: float = 0.1, seed: int = 0,
@@ -32,7 +42,7 @@ def sweep(dataset: str, *, scale: float = 0.1, seed: int = 0,
     q = max(N // 4, n // queries)
     oracle = WindowOracle(rows, N, ts)
     min_t = N  # evaluate only full windows
-    out = []
+    out, failures = [], []
     for eps in eps_list:
         for alg in algs:
             try:
@@ -66,9 +76,13 @@ def sweep(dataset: str, *, scale: float = 0.1, seed: int = 0,
                 print(f"  {spec.name:<10s} {alg:<5s} 1/eps={1/eps:4.0f} "
                       f"rows={peak:6d} avg={avg:.5f} max={worst:.5f} "
                       f"({wall:.1f}s)", flush=True)
-            except Exception as e:   # noqa: BLE001 — sweep robustness
-                print(f"  {dataset} {alg} eps={eps}: FAILED {e!r}",
-                      flush=True)
+            except Exception as e:   # noqa: BLE001 — finish the sweep,
+                # then fail it: every cell's traceback is printed here
+                failures.append(f"{dataset} {alg} eps={eps}: {e!r}")
+                print(f"  {failures[-1]}: FAILED", flush=True)
+                traceback.print_exc()
+    if failures:
+        raise SweepError(failures, out)
     return out
 
 
@@ -84,9 +98,12 @@ def main(argv=None) -> List[Dict]:
         kw["eps_list"] = args.eps
     if args.algs:
         kw["algs"] = args.algs
-    rows = sweep(args.dataset, scale=args.scale, **kw)
-    path = write_csv(f"error_space_{args.dataset}.csv", rows)
-    print("wrote", path)
+    try:
+        rows = sweep(args.dataset, scale=args.scale, **kw)
+    except SweepError as e:
+        print("wrote", write_csv(f"error_space_{args.dataset}.csv", e.rows))
+        raise
+    print("wrote", write_csv(f"error_space_{args.dataset}.csv", rows))
     return rows
 
 

@@ -13,6 +13,7 @@ Run:  PYTHONPATH=src:. python examples/quickstart.py   (from the repo root)
 """
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from repro.core.errors import cova_error
@@ -240,7 +241,10 @@ print(f"\nhistory plane: t={eng_h.t}, window W={W_h} → intervals up to "
 # processes (as compressed (2ℓ, d) node states over the jax.distributed
 # KV service).  Ingest routes by ownership; checkpoints are one shard
 # per process and restore on any process count.  This block spawns a
-# real 2-process CPU pair and checks both halves against the fleet above.
+# real 2-process CPU pair and checks both halves bit for bit against the
+# fleet above — so it runs only when this process is on the CPU too: on
+# an accelerator the reference answer comes from the chip, which this
+# process holds, and CPU children could not match it bit for bit.
 import os
 import socket
 import subprocess
@@ -274,7 +278,11 @@ np.save(sys.argv[3] + f"/g{pid}.npy",
 print(f"process {pid} owns [{topo.lo}, {topo.hi}) of {S}")
 """
 
-if os.environ.get("QUICKSTART_MULTIHOST", "1") != "0":
+if jax.default_backend() != "cpu":
+    print("\n2-process fleet: skipped — this process is on "
+          f"{jax.default_backend()!r}; the CPU pair is compared with a CPU "
+          "answer only")
+elif os.environ.get("QUICKSTART_MULTIHOST", "1") != "0":
     sock = socket.socket()
     sock.bind(("127.0.0.1", 0))
     port = str(sock.getsockname()[1])

@@ -6,45 +6,32 @@ touches jax device state."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def _axis_type_kw(n_axes: int) -> dict:
-    """``axis_types=`` kwarg when this jax has explicit axis types (≥ 0.5);
-    empty on older releases where every mesh axis is implicitly Auto."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
-def make_mesh_compat(shape, axes):
-    """Version-portable ``jax.make_mesh`` with Auto axis types."""
-    return jax.make_mesh(shape, axes, **_axis_type_kw(len(axes)))
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
-
-
-def make_debug_mesh(n_data: int = 2, n_model: int = 2):
-    """Small mesh for subprocess-based multi-device tests."""
-    return make_mesh_compat((n_data, n_model), ("data", "model"))
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """All local devices on 'data', no model parallelism."""
-    return make_mesh_compat((len(jax.devices()), 1), ("data", "model"))
+    return make_mesh((len(jax.devices()), 1), ("data", "model"))
 
 
 def make_local_mesh(axis: str = "streams"):
     """1-D mesh over THIS process's devices only (``jax.local_devices()``)
-    — the default fleet mesh.  Unlike ``make_mesh_compat`` (which fills
-    from the global device list), this can never silently span another
-    process's devices: multi-process fleets get one per-process mesh
-    each, coordinated by ``repro.parallel.topology.FleetTopology``."""
-    import numpy as np
-
-    devices = np.asarray(jax.local_devices())
-    return jax.sharding.Mesh(devices, (axis,), **_axis_type_kw(1))
+    — the default fleet mesh.  Unlike ``make_mesh`` without ``devices``
+    (which fills from the global device list), this can never silently
+    span another process's devices: multi-process fleets get one
+    per-process mesh each, coordinated by
+    ``repro.parallel.topology.FleetTopology``."""
+    devices = jax.local_devices()
+    return make_mesh((len(devices),), (axis,), devices=devices)

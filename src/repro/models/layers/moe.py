@@ -25,8 +25,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import MoECfg
-from repro.parallel.sharding import (current_mesh, current_rules,
-                                     shard_map_compat as shard_map)
+from repro.parallel.sharding import current_mesh, current_rules
 
 
 @dataclasses.dataclass(frozen=True)
@@ -136,7 +135,7 @@ def moe_block(x: jax.Array, wr: jax.Array, wg: jax.Array, wu: jax.Array,
     x_spec = P(bspec, None, None)
     body = partial(_local_moe, moe=moe, split=split, msize=info.msize,
                    axis=info.axis)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),

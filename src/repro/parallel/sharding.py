@@ -17,19 +17,6 @@ from typing import Dict, Optional, Tuple
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map as shard_map_compat  # jax ≥ 0.6
-except ImportError:  # jax < 0.6: experimental API, `check_vma` was `check_rep`
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=None,
-                         **kw):
-        """Version-portable ``shard_map`` (the repo-wide compat shim)."""
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-
 _ctx = threading.local()
 
 

@@ -321,9 +321,8 @@ class SketchFleetEngine:
         way, so pending rows always live in one structure)."""
         from repro.serve.ingest import AdmissionQueue, make_pipeline
 
-        sharding = self.fleet.meta.get("slab_sharding")
-        put = (jax.device_put if sharding is None
-               else (lambda slab: jax.device_put(slab, sharding)))
+        sharding = self.fleet.meta["slab_sharding"]
+        put = lambda slab: jax.device_put(slab, sharding)    # noqa: E731
         self.ingest = mode
         self.queue = AdmissionQueue(self.S_local, self.d, capacity=capacity)
         self.pipe = make_pipeline(mode, self.queue, block=self.block,
@@ -605,8 +604,10 @@ class SketchFleetEngine:
             # already holds?" — scoring post-update would let a burst
             # vouch for itself
             dev_scores = self.fleet.score(self.state, slab, self.t)
-        ts = jnp.arange(self.t + 1, self.t + self.block + 1, dtype=jnp.int32)
-        self.state = self.fleet.update_block(self.state, slab, ts)
+        # host stamps: the fleet replicates them onto its own mesh
+        ts = np.arange(self.t + 1, self.t + self.block + 1, dtype=np.int32)
+        prev = self.state
+        self.state = self.fleet.update_block(prev, slab, ts)
         # admission-to-device latency of this tick (prefetched slabs make
         # this ~the bare dispatch — the async pipeline's serving win)
         self.last_dispatch_s = time.perf_counter() - t_enter
@@ -629,6 +630,10 @@ class SketchFleetEngine:
         # double buffering: pack + prefetch the NEXT slab while the
         # device consumes the one just dispatched (no-op for sync)
         self.pipe.after_dispatch()
+        # ...but no further ahead: wait for the previous tick, so at most
+        # this one is in flight.  Unbounded, the host would queue a slab
+        # and a fleet state of device memory for every tick it ran ahead.
+        jax.block_until_ready(prev)
         return nrows
 
     def run(self, max_ticks: int = 10_000, *,

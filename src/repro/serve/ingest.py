@@ -47,9 +47,9 @@ with the device.  This module makes ingest a subsystem of its own:
     path.  The prefetch transfers a private copy of the packed slab
     (``device_put`` can be zero-copy on CPU, so transferring the reused
     buffer itself would alias host memory a later tick repacks under a
-    still-running update), which is what lets the pipeline run with no
-    cross-tick blocking: device compute is never waited on, only
-    dispatched past.
+    still-running update), so packing never waits on the device.  The
+    engine keeps at most one tick in flight: after packing slab *k+1*
+    it waits for tick *k−1*, never for tick *k*.
 
 Tick/clock contract (what makes async bit-identical to sync): a tick
 ingests, for every user, the first ``min(block, pending_u)`` rows of
@@ -447,7 +447,7 @@ class AsyncIngest:
     rows stay addressable for top-up and checkpoint unwind, the other
     packs the next tick.  The prefetch hands the device a private copy
     of the packed slab, so buffer reuse never races device compute and
-    the pipeline contains no cross-tick blocking at all.
+    the pipeline itself never waits on the device.
     """
 
     mode = "async"

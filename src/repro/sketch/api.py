@@ -770,8 +770,6 @@ def shard_streams(sk: SlidingSketch, streams: int, mesh=None, *,
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.parallel.sharding import shard_map_compat
-
     if sk.meta.get("backend") != "jax":
         raise ValueError(
             f"shard_streams requires a JAX-backed sketch, got {sk.name!r} "
@@ -802,18 +800,24 @@ def shard_streams(sk: SlidingSketch, streams: int, mesh=None, *,
     spec = P(axis)
     sharding = NamedSharding(mesh, spec)
 
-    def init(t0=1):
-        return jax.device_put(fleet.init(t0), sharding)
+    # built in place: each device materializes only its own stream shard
+    # (an eager init would first hold the whole fleet on one device)
+    init = jax.jit(fleet.init, out_shardings=sharding)
 
-    shard_block = jax.jit(shard_map_compat(
-        local.update_block, mesh=mesh,
-        in_specs=(spec, spec, spec), out_specs=spec,
-        check_vma=False))
+    def _block_program(ts_spec):
+        return jax.jit(jax.shard_map(
+            local.update_block, mesh=mesh,
+            in_specs=(spec, spec, ts_spec), out_specs=spec,
+            check_vma=False))
+
+    # shared (B,) tick stamps are replicated and broadcast per device;
+    # per-stream (S, B) stamps are sharded with the streams
+    shard_block_shared = _block_program(P())
+    shard_block = _block_program(spec)
 
     def update_block(state, rows, ts):
-        ts = jnp.asarray(ts, jnp.int32)
-        if ts.ndim == 1:
-            ts = jnp.broadcast_to(ts, (S, ts.shape[0]))
+        if not isinstance(ts, jax.Array):
+            ts = np.asarray(ts, np.int32)
         if not isinstance(rows, jax.Array):
             # host slab: place it along the stream axis here, explicitly.
             # An ingest pipeline that prefetched the slab with
@@ -821,6 +825,8 @@ def shard_streams(sk: SlidingSketch, streams: int, mesh=None, *,
             # already-placed device array flows into the jitted program
             # with no re-transfer.
             rows = jax.device_put(np.asarray(rows), sharding)
+        if ts.ndim == 1:
+            return shard_block_shared(state, rows, ts)
         return shard_block(state, rows, ts)
 
     # scoring as one shard_map'd SPMD program per slab — each device runs
@@ -829,10 +835,10 @@ def shard_streams(sk: SlidingSketch, streams: int, mesh=None, *,
     # and per-stream paths is pinned in tests/sketch/test_score.py)
     score = None
     if capability.has(local, "score"):
-        shard_sc_t = jax.jit(shard_map_compat(
+        shard_sc_t = jax.jit(jax.shard_map(
             local.score._vmapped_t, mesh=mesh,
             in_specs=(spec, spec, spec), out_specs=spec, check_vma=False))
-        shard_sc_nt = jax.jit(shard_map_compat(
+        shard_sc_nt = jax.jit(jax.shard_map(
             local.score._vmapped_nt, mesh=mesh,
             in_specs=(spec, spec), out_specs=spec, check_vma=False))
 
@@ -846,7 +852,7 @@ def shard_streams(sk: SlidingSketch, streams: int, mesh=None, *,
 
     ranks = None
     if capability.has(local, "ranks"):
-        shard_ranks = jax.jit(shard_map_compat(
+        shard_ranks = jax.jit(jax.shard_map(
             local.ranks._vmapped, mesh=mesh,
             in_specs=(spec,), out_specs=spec, check_vma=False))
 

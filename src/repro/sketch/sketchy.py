@@ -90,9 +90,11 @@ def sketchy_dsfd(cfg: SketchyConfig = SketchyConfig()) -> Optimizer:
                 d = p.shape[-1]
                 sliding = cfg.sketch(d)
                 g2 = gf.reshape(-1, d)
-                # feed FD-compressed row summary, unit-normalized
+                # feed FD-compressed row summary, unit-normalized: FD at
+                # ℓ keeps its top ℓ−1 directions, so ℓ = summary_rows + 1
+                # (ℓ = 1 keeps none and the sketch stays empty)
                 summary = fd_compress(
-                    g2, max(cfg.summary_rows // 2, 1))[: cfg.summary_rows]
+                    g2, cfg.summary_rows + 1)[: cfg.summary_rows]
                 scale2 = jnp.sum(g2 * g2)
                 nrm = jnp.linalg.norm(summary, axis=1, keepdims=True)
                 unit = summary / jnp.maximum(nrm, 1e-30)
@@ -107,7 +109,13 @@ def sketchy_dsfd(cfg: SketchyConfig = SketchyConfig()) -> Optimizer:
                 coef = g2 @ V.T                          # (n, r)
                 inv = 1.0 / jnp.sqrt(lam + cfg.rho)
                 low = (coef * inv[None, :]) @ V
-                tail = (g2 - coef @ V) / jnp.sqrt(cfg.rho)
+                # isotropic tail at the smallest live eigenvalue (Sketchy's
+                # escaped-mass estimate).  Scaled by the bare 1/√ρ instead,
+                # the tail swamps the kept directions and the trust region
+                # turns every step into a unit-RMS normalized-gradient step.
+                floor = jnp.min(jnp.where(lam > 0.0, lam, jnp.inf))
+                floor = jnp.where(jnp.isfinite(floor), floor, 0.0)
+                tail = (g2 - coef @ V) / jnp.sqrt(floor + cfg.rho)
                 upd = (low + tail).reshape(p.shape)
                 # trust-region style normalization (Sketchy App. B)
                 rms = jnp.sqrt(jnp.mean(jnp.square(upd)) + 1e-30)

@@ -173,8 +173,8 @@ def test_merge_additive_bound(A, B_mat, eps, R):
 @pytest.mark.parametrize("seed,eps,R", [(0, 0.25, 1.0), (1, 0.25, 16.0),
                                         (2, 0.5, 4.0), (3, 0.125, 16.0)])
 def test_merge_additive_bound_fixed_seeds(seed, eps, R):
-    """Deterministic fallback for containers without hypothesis — the same
-    additive-bound check on pinned draws (split point varies with seed)."""
+    """The same additive-bound check on pinned draws (split point varies
+    with seed) — fixed regression cases beside hypothesis's search."""
     rng = np.random.default_rng(seed)
     n, d = int(rng.integers(40, 160)), int(rng.integers(3, 12))
     k = int(rng.integers(8, n - 8))            # arbitrary split point

@@ -112,8 +112,6 @@ def test_gram_psd_and_symmetry_invariants():
 # shard — the lowering the fused fleet tick runs under)
 # ---------------------------------------------------------------------------
 
-from repro.parallel.sharding import shard_map_compat  # noqa: E402
-
 B = 4                                         # divisible by 1/2/4 devices
 
 
@@ -124,9 +122,9 @@ def _shard(fn):
     mesh = Mesh(np.array(jax.devices()), ("s",))
     n_in = 3 if fn.__code__.co_argcount == 3 else \
         (2 if fn.__code__.co_argcount == 2 else 1)
-    return shard_map_compat(jax.vmap(fn), mesh=mesh,
-                            in_specs=(P("s"),) * n_in, out_specs=P("s"),
-                            check_vma=False)
+    return jax.shard_map(jax.vmap(fn), mesh=mesh,
+                         in_specs=(P("s"),) * n_in, out_specs=P("s"),
+                         check_vma=False)
 
 
 @pytest.mark.parametrize("wrap", ["vmap", "shard_map"])

@@ -3,28 +3,27 @@ interval queries, ``repro.sketch.history``).
 
 The load-bearing pins: ``query_interval(t1, t2)`` over retired content
 is BIT-IDENTICAL to an independently reimplemented fold of the raw rows
-through the canonical dyadic schedule (the oracle below shares no code
-with the plane — scalar ``fd_compress`` calls, explicit recursion), on
-four paths: hot-only, cold-faulted (spill forced via a tiny hot tier),
-post-checkpoint-restore, and 2-process ``FleetTopology``.  Warm queries
-stay within the ``2⌈log₂(t2−t1)⌉`` node-merge budget.  Eviction
-(AggTree GC) and retirement (history index) are conserved on a shared
-clock sequence.
+through the canonical dyadic schedule (``repro.testing.IntervalOracle``
+shares no code with the plane — scalar ``fd_compress`` calls, explicit
+recursion), on four paths: hot-only, cold-faulted (spill forced via a
+tiny hot tier), post-checkpoint-restore, and 2-process
+``FleetTopology``.  Warm queries stay within the ``2⌈log₂(t2−t1)⌉``
+node-merge budget.  Eviction (AggTree GC) and retirement (history
+index) are conserved on a shared clock sequence.
 """
 
 import os
 import threading
 
 import numpy as np
-import jax.numpy as jnp
 import pytest
 
-from repro.core.fd import fd_compress
 from repro.serve.engine import SketchFleetEngine
 from repro.sketch.history import (HistoryPlane, dyadic_cover,
                                   install_query_interval,
                                   interval_merge_budget)
-from repro.sketch.query import Cohort, canonical_cover
+from repro.sketch.query import Cohort
+from repro.testing import IntervalOracle
 from repro.train.checkpoint import HISTORY_MARKER
 
 S, D, ELL, W, BLOCK, N = 8, 12, 4, 16, 4, 48
@@ -64,70 +63,6 @@ def _engine(rows, **kw):
             else:
                 eng.step(advance_time=True)
     return eng
-
-
-# ---------------------------------------------------------------------------
-# The independent oracle: the canonical dyadic schedule, reimplemented
-# ---------------------------------------------------------------------------
-
-
-class Oracle:
-    """From-scratch re-compression of the raw rows through the same
-    dyadic schedule the plane documents — scalar jitted ``fd_compress``
-    only (pinned bit-identical to the plane's vmapped path)."""
-
-    def __init__(self, rows, ell=ELL):
-        self.rows, self.ell, self.memo = rows, ell, {}
-
-    def _compress(self, mat):
-        return np.asarray(fd_compress(jnp.asarray(mat), self.ell))
-
-    def _merge2(self, a, b):
-        return self._compress(np.concatenate([a, b], axis=0))
-
-    def node(self, L, i):
-        key = (L, i)
-        if key in self.memo:
-            return self.memo[key]
-        if L == 0:
-            u = i
-            if u == 0 or u > self.rows.shape[1]:
-                v = None
-            else:
-                col = self.rows[:, u - 1, :]
-                v = (None if not col.any() else
-                     np.stack([self._compress(col[s][None])
-                               for s in range(S)]))
-        else:
-            a, b = self.node(L - 1, 2 * i), self.node(L - 1, 2 * i + 1)
-            v = (b if a is None else a if b is None else
-                 np.stack([self._merge2(a[s], b[s]) for s in range(S)]))
-        self.memo[key] = v
-        return v
-
-    def _seg(self, arr, lo, hi):
-        if hi - lo == 1:
-            return arr[lo]
-        mid = (lo + hi) // 2
-        return self._merge2(self._seg(arr, lo, mid),
-                            self._seg(arr, mid, hi))
-
-    def interval(self, t1, t2, ranges=((0, S),)):
-        segs = []
-        for lo, hi in ranges:
-            canonical_cover(0, S, lo, hi, segs)
-        acc = None
-        for L, i in dyadic_cover(t1, t2):
-            arr = self.node(L, i)
-            if arr is None:
-                continue
-            v = None
-            for lo, hi in segs:
-                sv = self._seg(arr, lo, hi)
-                v = sv if v is None else self._merge2(v, sv)
-            acc = v if acc is None else self._merge2(acc, v)
-        return (np.zeros((2 * self.ell, D), np.float32) if acc is None
-                else acc)
 
 
 INTERVALS = [(1, 33), (0, 33), (5, 29), (16, 17), (1, 2), (7, 23)]
@@ -170,12 +105,12 @@ def test_hot_only_bit_identical_to_oracle():
     rows = _rows()
     eng = _engine(rows)
     assert eng.history.retired_through == eng.t - W == 32
-    oracle = Oracle(rows)
+    oracle = IntervalOracle(rows, ELL)
     for t1, t2 in INTERVALS:
         for users, ranges in COHORTS:
             np.testing.assert_array_equal(
                 eng.query_interval(users, t1, t2),
-                oracle.interval(t1, t2, ranges))
+                oracle.interval(t1, t2, S, ranges))
     # nothing spilled, nothing faulted on the unbounded hot tier
     assert eng.history.store.spills == 0
     assert eng.history.store.faults == 0
@@ -205,12 +140,12 @@ def test_cold_faulted_bit_identical(tmp_path):
     step = os.path.join(spill, node, "step_000000000")
     assert os.path.isfile(os.path.join(step, "manifest.json"))
     f0 = st.faults
-    oracle = Oracle(rows)
+    oracle = IntervalOracle(rows, ELL)
     for t1, t2 in INTERVALS:
         for users, ranges in COHORTS:
             np.testing.assert_array_equal(
                 eng.query_interval(users, t1, t2),
-                oracle.interval(t1, t2, ranges))
+                oracle.interval(t1, t2, S, ranges))
     assert st.faults > f0                           # answers crossed tiers
 
 
@@ -294,13 +229,13 @@ def test_idle_advance_time_ticks_retire():
     rows = _rows(idle_ticks=(2, 3))
     eng = _engine(rows)
     assert eng.history.retired_through == 32     # idle ticks aged the clock
-    oracle = Oracle(rows)
+    oracle = IntervalOracle(rows, ELL)
     # an interval fully inside the idle region is the zero sketch
     idle = eng.query_interval(None, 2 * BLOCK + 1, 4 * BLOCK + 1)
     assert not idle.any()
     for t1, t2 in [(1, 33), (5, 29), (9, 17)]:   # spans crossing the gap
         np.testing.assert_array_equal(eng.query_interval(None, t1, t2),
-                                      oracle.interval(t1, t2))
+                                      oracle.interval(t1, t2, S))
     # clock-neutral idle polls retire nothing
     r0, t0 = eng.history.retired_units, eng.t
     eng.step()

@@ -21,6 +21,7 @@ from repro.sketch.api import (ALL, Cohort, FleetSpace, agg_tree, make_sketch,
                               merge_streams, query_cohort, shard_streams,
                               vmap_streams)
 from repro.sketch.query import AggTree, as_cohort, full_reduce_streams
+from repro.testing import cohort_fold
 
 
 def _streams(S, n, d, seed=3):
@@ -36,43 +37,6 @@ def _assert_trees_equal(a, b, msg=""):
     for x, y in zip(la, lb):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
                                       err_msg=msg)
-
-
-def _fold_oracle(base, state, lo, hi, t, jm):
-    """Independent from-scratch reference: midpoint-split merge fold of
-    streams [lo, hi) at query time t (the AggTree's documented schedule,
-    reimplemented here rather than shared)."""
-    if hi - lo == 1:
-        return jax.tree.map(lambda x: x[lo], state)
-    mid = (lo + hi) // 2
-    return jm(_fold_oracle(base, state, lo, mid, t, jm),
-              _fold_oracle(base, state, mid, hi, t, jm),
-              jnp.asarray(t, jnp.int32))
-
-
-def _cohort_oracle(base, state, S, ranges, t):
-    """From-scratch cohort reference: canonical segment-tree cover of each
-    range (midpoint recursion over [0, S)), folded left-to-right."""
-    jm = jax.jit(lambda a, b, tt: base.merge(a, b, tt))
-    segs = []
-
-    def cover(lo, hi, qlo, qhi):
-        if qlo <= lo and hi <= qhi:
-            segs.append((lo, hi))
-            return
-        mid = (lo + hi) // 2
-        if qlo < mid:
-            cover(lo, mid, qlo, min(qhi, mid))
-        if qhi > mid:
-            cover(mid, hi, max(qlo, mid), qhi)
-
-    for lo, hi in ranges:
-        cover(0, S, lo, hi)
-    acc = None
-    for lo, hi in segs:
-        node = _fold_oracle(base, state, lo, hi, t, jm)
-        acc = node if acc is None else jm(acc, node, jnp.asarray(t, jnp.int32))
-    return acc
 
 
 # ---------------------------------------------------------------------------
@@ -175,7 +139,7 @@ def test_query_cohort_matches_fold(S, name, hyper):
 
     g = query_cohort(fleet, state, ALL, n)
     _assert_trees_equal(
-        g, _cohort_oracle(sk, state, S, [(0, S)], n),
+        g, cohort_fold(sk, state, S, [(0, S)], n),
         f"{name} S={S}: query_cohort(ALL) != from-scratch fold")
 
     rng = np.random.default_rng(17)
@@ -188,7 +152,7 @@ def test_query_cohort_matches_fold(S, name, hyper):
         for c in cohorts:
             got = query_cohort(fleet, state, c, n)
             _assert_trees_equal(
-                got, _cohort_oracle(sk, state, S, c.resolve(S), n),
+                got, cohort_fold(sk, state, S, c.resolve(S), n),
                 f"{name} S={S}: cohort {c} != from-scratch fold")
 
 
@@ -205,7 +169,7 @@ def test_merge_streams_is_deprecated_query_cohort_all_alias():
     _assert_trees_equal(merged, query_cohort(fleet, state, ALL, n))
     # and the alias is correct for arbitrary (non-power-of-two) S: the
     # pad-free midpoint split, pinned against the independent oracle
-    _assert_trees_equal(merged, _cohort_oracle(sk, state, S, [(0, S)], n))
+    _assert_trees_equal(merged, cohort_fold(sk, state, S, [(0, S)], n))
 
 
 def test_merge_streams_warning_points_at_the_caller():
@@ -304,7 +268,7 @@ def test_warm_cohort_query_merge_budget_1024_streams():
     c = Cohort.range(lo, lo + 24)
     _assert_trees_equal(
         query_cohort(fleet, state, c, n),
-        _cohort_oracle(sk, state, S, c.resolve(S), n),
+        cohort_fold(sk, state, S, c.resolve(S), n),
         "warm cohort answer != from-scratch fold")
 
 
@@ -329,7 +293,7 @@ def test_unannounced_state_change_resets_cache():
     got = query_cohort(fleet, state2, Cohort.range(2, 7), 2 * n)
     assert tree.resets == 1                     # wholesale, sound
     _assert_trees_equal(
-        got, _cohort_oracle(sk, state2, S, ((2, 7),), 2 * n),
+        got, cohort_fold(sk, state2, S, ((2, 7),), 2 * n),
         "post-reset answer != from-scratch fold on the new state")
 
 
@@ -354,7 +318,7 @@ def test_advance_dirties_only_touched_paths():
     assert tree.resets == 0                     # announced, not a reset
     got = tree.query(state2, ALL, n + 1)
     _assert_trees_equal(
-        got, _cohort_oracle(sk, state2, S, ((0, S),), n + 1),
+        got, cohort_fold(sk, state2, S, ((0, S),), n + 1),
         "post-advance answer != from-scratch fold")
 
     # superseded-tag GC: a later query retags only its own path; the next

@@ -11,7 +11,7 @@ import pytest
 
 from repro.configs.base import get_config
 from repro.data.tokens import TokenPipeline
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 from repro.models import api
 from repro.models.params import init_params
 from repro.train import checkpoint as ckpt
@@ -20,7 +20,7 @@ from repro.train.train_step import TrainStepConfig
 
 
 def _mesh1():
-    return make_mesh_compat((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 @pytest.fixture(scope="module")
@@ -45,7 +45,7 @@ def test_checkpoint_resume_and_elastic(tiny_cfg, tmp_path):
     assert ckpt.latest_step(d) == 6
     # resume on a *different* mesh layout (elastic restart): same 1 device,
     # but a (1,) pure-data mesh exercises restore-with-resharding.
-    mesh2 = make_mesh_compat((1,), ("data",))
+    mesh2 = make_mesh((1,), ("data",))
     r2 = train(tiny_cfg, mesh2,
                loop=LoopConfig(steps=10, ckpt_dir=d, ckpt_every=4),
                seq_len=32, global_batch=4)
