@@ -164,22 +164,25 @@ def _dump_sorted_rows(sk: SketchState, rows, nrows, now, theta) -> SketchState:
     """Given SVD-sorted rows, dump every row with ‖row‖² ≥ θ into the ring
     (Algorithm 2 lines 9-11), then compact the remaining rows to the top."""
     m = rows.shape[0]
-    norms = jnp.sum(rows * rows, axis=1)
-    ndump = jnp.sum((norms >= theta).astype(jnp.int32))  # sorted ⇒ prefix
+    with jax.named_scope("dsfd.dump"):
+        norms = jnp.sum(rows * rows, axis=1)
+        # sorted ⇒ prefix
+        ndump = jnp.sum((norms >= theta).astype(jnp.int32))
 
-    def body(j, sk):
-        def do(sk):
-            s = jnp.where(j == 0, sk.last_t + 1, now)
-            return _ring_append(sk, rows[j], s, now)
-        return jax.lax.cond(j < ndump, do, lambda sk: sk, sk)
+        def body(j, sk):
+            def do(sk):
+                s = jnp.where(j == 0, sk.last_t + 1, now)
+                return _ring_append(sk, rows[j], s, now)
+            return jax.lax.cond(j < ndump, do, lambda sk: sk, sk)
 
-    sk = jax.lax.fori_loop(0, m, body, sk)
+        sk = jax.lax.fori_loop(0, m, body, sk)
 
-    kept = jnp.roll(rows, -ndump, axis=0)
-    nkeep = jnp.maximum(nrows - ndump, 0)
-    kept = jnp.where(jnp.arange(m)[:, None] < nkeep, kept, 0.0)
-    sig1 = jnp.sum(kept[0] * kept[0])
-    return sk._replace(buf=kept, nbuf=nkeep.astype(jnp.int32), sig1=sig1)
+        kept = jnp.roll(rows, -ndump, axis=0)
+        nkeep = jnp.maximum(nrows - ndump, 0)
+        kept = jnp.where(jnp.arange(m)[:, None] < nkeep, kept, 0.0)
+        sig1 = jnp.sum(kept[0] * kept[0])
+        return sk._replace(buf=kept, nbuf=nkeep.astype(jnp.int32),
+                           sig1=sig1)
 
 
 # ---------------------------------------------------------------------------
@@ -274,41 +277,54 @@ def _krylov_dumps(cfg: DSFDConfig, sk: SketchState, now, theta) -> SketchState:
 
 
 def _absorb(cfg: DSFDConfig, sk: SketchState, row, now, theta) -> SketchState:
-    """Insert one row, then merge/dump per the configured cadence."""
-    buf = jax.lax.dynamic_update_index_in_dim(sk.buf, row, sk.nbuf, 0)
-    e = jnp.sum(row * row)
-    sk = sk._replace(buf=buf, nbuf=sk.nbuf + 1, sig1=sk.sig1 + e,
-                     energy=sk.energy + e)
+    """Insert one row, then merge/dump per the configured cadence.
 
-    full = sk.nbuf >= cfg.m
-    hot = sk.sig1 >= theta
+    Named scopes (``dsfd.absorb`` around the whole, inside it
+    ``dsfd.insert``, ``dsfd.shrink``, ``dsfd.rotate``, ``dsfd.dump`` and
+    ``dsfd.krylov``) land in the compiled program's ``op_name`` metadata,
+    so a device trace's ops can be attributed to the phase they run.
+    Under ``vmap`` every ``cond`` here becomes a ``select``: both branches
+    run for every row, and the selects themselves are ``dsfd.absorb``'s."""
+    with jax.named_scope("dsfd.absorb"):
+        with jax.named_scope("dsfd.insert"):
+            buf = jax.lax.dynamic_update_index_in_dim(sk.buf, row, sk.nbuf,
+                                                      0)
+            e = jnp.sum(row * row)
+            sk = sk._replace(buf=buf, nbuf=sk.nbuf + 1, sig1=sk.sig1 + e,
+                             energy=sk.energy + e)
 
-    def svd_merge(sk):
-        # Buffer full → FD shrink (+ dump check on the sorted rows).
-        rows, _, _ = fd_shrink(sk.buf, cfg.ell)
-        return _dump_sorted_rows(sk, rows, jnp.asarray(cfg.ell - 1, jnp.int32),
-                                 now, theta)
+        full = sk.nbuf >= cfg.m
+        hot = sk.sig1 >= theta
 
-    def rotate_dump(sk):
-        # θ-trigger between merges → lossless rotate + dump (no shrink).
-        rows, _ = fd_rotate(sk.buf)
-        nrows = jnp.minimum(sk.nbuf, min(cfg.m, cfg.d))
-        return _dump_sorted_rows(sk, rows, nrows, now, theta)
+        def svd_merge(sk):
+            # Buffer full → FD shrink (+ dump check on the sorted rows).
+            with jax.named_scope("dsfd.shrink"):
+                rows, _, _ = fd_shrink(sk.buf, cfg.ell)
+            return _dump_sorted_rows(
+                sk, rows, jnp.asarray(cfg.ell - 1, jnp.int32), now, theta)
 
-    def krylov_dump(sk):
-        return _krylov_dumps(cfg, sk, now, theta)
+        def rotate_dump(sk):
+            # θ-trigger between merges → lossless rotate + dump (no shrink).
+            with jax.named_scope("dsfd.rotate"):
+                rows, _ = fd_rotate(sk.buf)
+                nrows = jnp.minimum(sk.nbuf, min(cfg.m, cfg.d))
+            return _dump_sorted_rows(sk, rows, nrows, now, theta)
 
-    if cfg.mode == "exact":
-        # SVD every step: rotate+dump, then shrink only if genuinely full.
-        sk = rotate_dump(sk)
-        sk = jax.lax.cond(sk.nbuf >= cfg.m, svd_merge, lambda s: s, sk)
+        def krylov_dump(sk):
+            with jax.named_scope("dsfd.krylov"):
+                return _krylov_dumps(cfg, sk, now, theta)
+
+        if cfg.mode == "exact":
+            # SVD every step: rotate+dump, then shrink only if truly full.
+            sk = rotate_dump(sk)
+            sk = jax.lax.cond(sk.nbuf >= cfg.m, svd_merge, lambda s: s, sk)
+            return sk
+
+        hot_path = krylov_dump if cfg.mode == "krylov" else rotate_dump
+        sk = jax.lax.cond(
+            full, svd_merge,
+            lambda s: jax.lax.cond(hot, hot_path, lambda x: x, s), sk)
         return sk
-
-    hot_path = krylov_dump if cfg.mode == "krylov" else rotate_dump
-    sk = jax.lax.cond(
-        full, svd_merge, lambda s: jax.lax.cond(hot, hot_path, lambda x: x, s),
-        sk)
-    return sk
 
 
 # ---------------------------------------------------------------------------
@@ -333,8 +349,9 @@ def dsfd_update(cfg: DSFDConfig, state: DSFDState, row: jax.Array, now,
         1.0 * cfg.ell * theta if swap_energy is None else swap_energy,
         jnp.float32)
 
-    main = _expire(state.main, now, cfg.window)
-    aux = _expire(state.aux, now, cfg.window)
+    with jax.named_scope("dsfd.expire"):
+        main = _expire(state.main, now, cfg.window)
+        aux = _expire(state.aux, now, cfg.window)
 
     # Restart-every-N generalized: promote the auxiliary once it has absorbed
     # swap_energy = ℓθ (== N steps in the normalized model; the retiring
@@ -343,8 +360,9 @@ def dsfd_update(cfg: DSFDConfig, state: DSFDState, row: jax.Array, now,
         main, aux = ma
         return aux, _sketch_init(cfg, now)
 
-    main, aux = jax.lax.cond(
-        aux.energy >= swap_energy, do_swap, lambda ma: ma, (main, aux))
+    with jax.named_scope("dsfd.swap"):
+        main, aux = jax.lax.cond(
+            aux.energy >= swap_energy, do_swap, lambda ma: ma, (main, aux))
 
     e = jnp.sum(row * row)
 
@@ -356,18 +374,20 @@ def dsfd_update(cfg: DSFDConfig, state: DSFDState, row: jax.Array, now,
     def idle(ma):  # time-based idle tick (‖a‖² = 0): expiry/swap only
         return ma
 
-    if bypass:
-        def heavy(ma):
-            main, aux = ma
-            return (_ring_append(main, row, main.last_t + 1, now),
-                    _ring_append(aux, row, aux.last_t + 1, now))
+    # the selects that merge these branches under vmap are absorb work
+    with jax.named_scope("dsfd.absorb"):
+        if bypass:
+            def heavy(ma):
+                main, aux = ma
+                return (_ring_append(main, row, main.last_t + 1, now),
+                        _ring_append(aux, row, aux.last_t + 1, now))
 
-        main, aux = jax.lax.cond(
-            e >= theta, heavy,
-            lambda ma: jax.lax.cond(e > 0.0, light, idle, ma),
-            (main, aux))
-    else:
-        main, aux = jax.lax.cond(e > 0.0, light, idle, (main, aux))
+            main, aux = jax.lax.cond(
+                e >= theta, heavy,
+                lambda ma: jax.lax.cond(e > 0.0, light, idle, ma),
+                (main, aux))
+        else:
+            main, aux = jax.lax.cond(e > 0.0, light, idle, (main, aux))
     return DSFDState(main=main, aux=aux)
 
 

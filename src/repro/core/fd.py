@@ -80,14 +80,20 @@ def fd_shrink(buf: jax.Array, ell: int) -> Tuple[jax.Array, jax.Array, jax.Array
 
 
 def fd_update(state: FDState, row: jax.Array, *, ell: int) -> FDState:
-    """Absorb one row (FastFD cadence: shrink only when the buffer fills)."""
+    """Absorb one row (FastFD cadence: shrink only when the buffer fills).
+
+    The ``fd.insert`` and ``fd.shrink`` named scopes mark its two phases
+    in the compiled program's ``op_name`` metadata."""
     m = state.buf.shape[0]
-    buf = jax.lax.dynamic_update_index_in_dim(state.buf, row, state.nbuf, 0)
-    nbuf = state.nbuf + 1
+    with jax.named_scope("fd.insert"):
+        buf = jax.lax.dynamic_update_index_in_dim(state.buf, row, state.nbuf,
+                                                  0)
+        nbuf = state.nbuf + 1
 
     def do_shrink(args):
         buf, nbuf, shed = args
-        rows, _, delta = fd_shrink(buf, ell)
+        with jax.named_scope("fd.shrink"):
+            rows, _, delta = fd_shrink(buf, ell)
         return rows, jnp.asarray(ell - 1, jnp.int32), shed + delta
 
     def no_shrink(args):
@@ -116,10 +122,12 @@ def fd_absorb(state: FDState, rows: jax.Array, *, ell: int) -> FDState:
 @functools.partial(jax.jit, static_argnames=("ell",))
 def fd_compress(mat: jax.Array, ell: int) -> jax.Array:
     """Compress an (n, d) matrix to a (2ℓ, d) FD sketch buffer (≤ ℓ-1 + tail
-    nonzero rows).  Used by queries to merge snapshots with the residual."""
-    st = fd_init(ell, mat.shape[1], mat.dtype)
-    st = fd_absorb(st, mat, ell=ell)
-    return st.buf
+    nonzero rows).  Used by queries to merge snapshots with the residual;
+    its program's ops carry the ``fd.compress`` named scope."""
+    with jax.named_scope("fd.compress"):
+        st = fd_init(ell, mat.shape[1], mat.dtype)
+        st = fd_absorb(st, mat, ell=ell)
+        return st.buf
 
 
 def fd_query(state: FDState) -> jax.Array:

@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import api
+from repro.serve import trace
 from repro.serve.serve_step import build_decode_step, build_prefill_step
 
 
@@ -265,6 +266,7 @@ class SketchFleetEngine:
         self.t = 0                                  # fleet clock (ticks)
         self.rows_ingested = 0
         self._wire_ingest(ingest, queue_capacity)
+        self._wire_trace()
         # the cohort-query cache, shared with the fleet's query_cohort path
         self.tree = agg_tree(self.fleet)
         # the persistent sketch plane: with history=True, window expiry
@@ -328,7 +330,24 @@ class SketchFleetEngine:
         self.pipe = make_pipeline(mode, self.queue, block=self.block,
                                   put=put)
         self._zero_slab = None         # lazy zero slab for idle ticks
-        self.last_dispatch_s = 0.0     # admission-to-device latency
+
+    def _wire_trace(self) -> None:
+        """The tick log and the counters' baseline (also the restore
+        path; see ``ticks`` and ``counters``)."""
+        self.log = trace.TickLog(self.S_local * self.block)
+        self._census = None            # None: the fleet as initialized
+        lower = self.fleet.update_block.lower
+        avals = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=x.sharding), self.state)
+        slab = jax.ShapeDtypeStruct(
+            (self.S_local, self.block, self.d), jnp.float32,
+            sharding=self.fleet.meta["slab_sharding"])
+        ts = jax.ShapeDtypeStruct((self.block,), jnp.int32)
+        lower_update = lambda: lower(avals, slab, ts)        # noqa: E731
+        self._lower_update = lower_update
+        # the log may outlive the engine: hold no reference to it
+        self.log.update_hlo = lambda: lower_update().compile().as_text()
 
     @property
     def _pending(self) -> List[deque]:
@@ -469,6 +488,9 @@ class SketchFleetEngine:
         # either way — the pipeline is not part of the persisted state)
         eng._wire_ingest(espec.get("ingest", "async"),
                          espec.get("queue_capacity"))
+        eng._wire_trace()
+        if trace.has_census(eng.state):
+            eng._census = trace.census(eng.state)
         users, rows = fc.aux["pending_user"], fc.aux["pending_rows"]
         if topology is not None:
             # shard checkpoints carry GLOBAL pending ids (possibly from a
@@ -586,55 +608,67 @@ class SketchFleetEngine:
         no-op) unless ``advance_time=True`` — polling an idle engine
         must not silently expire live window content; wall-clock-driven
         time-based windows opt in to idle aging explicitly.
+
+        Every call, an idle poll too, is one record of :meth:`ticks`,
+        its phases timed as the spans of ``repro.serve.trace``.
         """
-        t_enter = time.perf_counter()
-        slab, touched, counts, nrows = self.pipe.next_slab()
-        if nrows == 0 and not advance_time:
-            self.last_dispatch_s = 0.0     # idle: nothing was dispatched
-            return 0
-        if nrows == 0:
-            if self._zero_slab is None:
-                self._zero_slab = np.zeros(
-                    (self.S_local, self.block, self.d), np.float32)
-            slab = self._zero_slab
-        dev_scores = None
-        if self.score_plane is not None and nrows:
-            # score the slab against the PRE-update window basis at the
-            # current clock: "is this row explained by what the window
-            # already holds?" — scoring post-update would let a burst
-            # vouch for itself
-            dev_scores = self.fleet.score(self.state, slab, self.t)
-        # host stamps: the fleet replicates them onto its own mesh
-        ts = np.arange(self.t + 1, self.t + self.block + 1, dtype=np.int32)
-        prev = self.state
-        self.state = self.fleet.update_block(prev, slab, ts)
-        # admission-to-device latency of this tick (prefetched slabs make
-        # this ~the bare dispatch — the async pipeline's serving win)
-        self.last_dispatch_s = time.perf_counter() - t_enter
-        self.t += self.block
-        self.rows_ingested += nrows
-        self.tree.advance(self.state, touched)
-        if dev_scores is not None:
-            cnt = np.zeros((self.S_local,), np.int64)
-            cnt[touched] = counts
-            self.score_plane.observe(np.asarray(dev_scores), cnt)
-        if self.history is not None:
-            # the persistent plane is host-side: observe the slab's raw
-            # units (one host copy — the opt-in cost of history), then
-            # retire exactly the units this clock advance expired.  Idle
-            # advance_time ticks land here too (their zero slab retires
-            # as empty nodes); clock-neutral idle polls returned above.
-            self.history.observe_block(np.asarray(slab),
-                                       first_ts=self.t - self.block + 1)
-            self.history.retire_through(self.t - self.window)
-        # double buffering: pack + prefetch the NEXT slab while the
-        # device consumes the one just dispatched (no-op for sync)
-        self.pipe.after_dispatch()
-        # ...but no further ahead: wait for the previous tick, so at most
-        # this one is in flight.  Unbounded, the host would queue a slab
-        # and a fleet state of device memory for every tick it ran ahead.
-        jax.block_until_ready(prev)
-        return nrows
+        log = self.log
+        with log.step(self.t):
+            with log.span(trace.NEXT_SLAB):
+                slab, touched, counts, nrows = self.pipe.next_slab()
+            log.taken(nrows, len(touched))
+            if nrows == 0 and not advance_time:
+                return 0                   # idle: nothing is dispatched
+            if nrows == 0:
+                if self._zero_slab is None:
+                    self._zero_slab = np.zeros(
+                        (self.S_local, self.block, self.d), np.float32)
+                slab = self._zero_slab
+            dev_scores = None
+            if self.score_plane is not None and nrows:
+                # score the slab against the PRE-update window basis at
+                # the current clock: "is this row explained by what the
+                # window already holds?" — scoring post-update would let
+                # a burst vouch for itself
+                with log.span(trace.SCORE):
+                    dev_scores = self.fleet.score(self.state, slab, self.t)
+            prev = self.state
+            with log.span(trace.DISPATCH):
+                # host stamps: the fleet replicates them onto its own mesh
+                ts = np.arange(self.t + 1, self.t + self.block + 1,
+                               dtype=np.int32)
+                self.state = self.fleet.update_block(prev, slab, ts)
+            self.t += self.block
+            self.rows_ingested += nrows
+            with log.span(trace.TREE_ADVANCE):
+                self.tree.advance(self.state, touched)
+            if dev_scores is not None:
+                with log.span(trace.SCORE_OBSERVE):
+                    cnt = np.zeros((self.S_local,), np.int64)
+                    cnt[touched] = counts
+                    self.score_plane.observe(np.asarray(dev_scores), cnt)
+            if self.history is not None:
+                # the persistent plane is host-side: observe the slab's
+                # raw units (one host copy — the opt-in cost of history),
+                # then retire exactly the units this clock advance
+                # expired.  Idle advance_time ticks land here too (their
+                # zero slab retires as empty nodes); clock-neutral idle
+                # polls returned above.
+                with log.span(trace.HISTORY):
+                    self.history.observe_block(
+                        np.asarray(slab), first_ts=self.t - self.block + 1)
+                    self.history.retire_through(self.t - self.window)
+            # double buffering: pack + prefetch the NEXT slab while the
+            # device consumes the one just dispatched (no-op for sync)
+            with log.span(trace.PREFETCH):
+                self.pipe.after_dispatch()
+            # ...but no further ahead: wait for the previous tick, so at
+            # most this one is in flight.  Unbounded, the host would queue
+            # a slab and a fleet state of device memory for every tick it
+            # ran ahead.
+            with log.span(trace.WAIT):
+                jax.block_until_ready(prev)
+            return nrows
 
     def run(self, max_ticks: int = 10_000, *,
             on_budget: str = "raise") -> int:
@@ -661,6 +695,66 @@ class SketchFleetEngine:
                 raise IngestBacklogError(msg, self.backlog)
             warnings.warn(msg, RuntimeWarning, stacklevel=2)
         return ticks
+
+    # -- what the engine records about itself --------------------------------
+
+    def ticks(self) -> np.ndarray:
+        """The engine's record of its last ticks (up to 4,096), oldest
+        first: a structured array of ``repro.serve.trace.RECORD``.
+
+        One record per ``step()`` call, idle polls included: ``step``
+        (the call's count) and ``clock`` (the engine clock before it),
+        ``start``/``end`` of the whole call, ``<phase>_start`` and
+        ``<phase>_end`` on ``time.perf_counter`` for each of
+        ``repro.serve.trace.PHASES`` (NaN where the tick did not run the
+        phase), ``rows`` taken, ``users`` touched and ``occupancy`` =
+        rows ÷ (streams × block).  For example, the host's own time in a
+        tick is ``end - start - (wait_end - wait_start)``; the time to
+        enqueue its update is ``dispatch_end - start``.  Recording costs
+        a few clock reads a tick; the same spans appear in a JAX
+        profiler trace as ``engine.*`` annotations while one runs."""
+        return self.log.records()
+
+    def counters(self) -> Dict[str, Optional[int]]:
+        """Device counters since the previous call (or since the engine
+        was built or restored), from one small program over the fleet
+        state; nothing is counted on the tick path.
+
+        ``dumped``: snapshots dumped into the rings, main and aux summed
+        over the fleet; ``left``: snapshots that left a ring by expiry or
+        eviction; ``swaps``: main/aux swaps (see
+        ``repro.serve.trace.census_diff`` for when a read must come at
+        least once per swap period to be exact); these are ``None`` for a
+        variant without snapshot rings.  ``peak_bytes_in_use``: the
+        largest device's peak (``None`` where the runtime keeps no
+        statistics), beside ``update_*_bytes`` from the update program's
+        ``memory_analysis()``."""
+        out: Dict[str, Optional[int]] = dict.fromkeys(
+            ("dumped", "left", "swaps"))
+        if trace.has_census(self.state):
+            now = trace.census(self.state)
+            before = (trace.initial_census(self.state)
+                      if self._census is None else self._census)
+            out.update({k: int(v) for k, v in
+                        trace.census_diff(before, now).items()})
+            self._census = now
+        devices = self.fleet.meta["slab_sharding"].device_set
+        stats = [d.memory_stats() for d in devices]
+        out["peak_bytes_in_use"] = (
+            max(int(s.get("peak_bytes_in_use", 0)) for s in stats)
+            if all(stats) else None)
+        mem = self.compiled_update().memory_analysis()
+        for k in ("argument", "output", "temp", "generated_code"):
+            v = getattr(mem, f"{k}_size_in_bytes", None)
+            out[f"update_{k}_bytes"] = None if v is None else int(v)
+        return out
+
+    def compiled_update(self):
+        """The fleet update program as compiled for this engine's shapes
+        (a ``jax.stages.Compiled``, from the compile cache once a tick
+        has run): ``as_text()`` carries the ``dsfd.*`` named scopes in
+        its ``op_name`` metadata, ``memory_analysis()`` its bytes."""
+        return self._lower_update().compile()
 
     # -- queries -----------------------------------------------------------
 

@@ -597,8 +597,12 @@ def vmap_streams(sk: SlidingSketch, streams: int) -> SlidingSketch:
     v_ranks = jax.vmap(sk.ranks) if capability.has(sk, "ranks") else None
     score = None
     if raw is not None:
-        v_raw_t = jax.vmap(raw, in_axes=(0, 0, 0))
-        v_raw_nt = jax.vmap(lambda s, x: raw(s, x, None))
+        def scoped(s, x, t):
+            with jax.named_scope("fleet.score"):
+                return raw(s, x, t)
+
+        v_raw_t = jax.vmap(scoped, in_axes=(0, 0, 0))
+        v_raw_nt = jax.vmap(lambda s, x: scoped(s, x, None))
         j_raw_t = jax.jit(v_raw_t)
         j_raw_nt = jax.jit(v_raw_nt)
 
@@ -828,6 +832,14 @@ def shard_streams(sk: SlidingSketch, streams: int, mesh=None, *,
         if ts.ndim == 1:
             return shard_block_shared(state, rows, ts)
         return shard_block(state, rows, ts)
+
+    def lower_update_block(state, rows, ts):
+        """Lower the update program for these arguments (shapes, dtypes
+        and shardings suffice; a host slab is placed along the streams)."""
+        prog = shard_block_shared if len(ts.shape) == 1 else shard_block
+        return prog.lower(state, rows, ts)
+
+    update_block.lower = lower_update_block
 
     # scoring as one shard_map'd SPMD program per slab — each device runs
     # the local fleet's vmapped residual program on its own stream shard,

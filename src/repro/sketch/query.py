@@ -255,6 +255,12 @@ def as_cohort(users) -> Cohort:
 # ---------------------------------------------------------------------------
 
 
+def _scoped_merge(base, a, b, t):
+    """The node merge, its ops under the ``fleet.query`` named scope."""
+    with jax.named_scope("fleet.query"):
+        return base.merge(a, b, t)
+
+
 class AggTree:
     """Segment tree of partial merges over a fleet's stream axis.
 
@@ -278,7 +284,7 @@ class AggTree:
         self.S = int(streams)
         if self.S < 1:
             raise ValueError(f"fleet size {streams} < 1")
-        self._jmerge = jax.jit(lambda a, b, t: base.merge(a, b, t))
+        self._jmerge = jax.jit(lambda a, b, t: _scoped_merge(base, a, b, t))
         # (lo, hi) -> (t_tag, merged base state)
         self._nodes: Dict[Tuple[int, int], Tuple[Optional[int], Any]] = {}
         # (resolved ranges, t_tag) -> composed result state

@@ -149,3 +149,30 @@ def test_four_chip_fleet_program_holds_a_quarter_per_chip(v5e_devices):
     assert init.output_size_in_bytes <= state_quarter, (
         f"init builds {init.output_size_in_bytes / 1e9:.3f} GB on a chip, "
         f"more than its quarter {state_quarter / 1e9:.3f} GB")
+
+
+def test_update_program_for_v5e_keeps_the_dsfd_scopes(v5e_device):
+    """The device trace's ops are attributed to DS-FD phases through the
+    compiled program's ``op_name`` metadata: on the chip's compiler every
+    loop of the update but the row scan itself, and every ``EighTpu`` of
+    its SVDs, must still carry a ``dsfd.*`` scope."""
+    import re
+
+    fleet, state, rows = _smoke_fleet([v5e_device], 256)
+    ts = _spec((8,), jnp.int32, SingleDeviceSharding(v5e_device))
+    text = fleet.update_block.lower(state, rows, ts).compile().as_text()
+
+    def scope(line):
+        op = re.search(r'op_name="([^"]*)"', line)
+        parts = op.group(1).split("/") if op else []
+        return [p for p in parts if p.startswith("dsfd.")]
+
+    loops = [ln for ln in text.splitlines() if " while(" in ln]
+    unscoped = [ln.split(" = ")[0].strip() for ln in loops if not scope(ln)]
+    assert len(loops) > 10 and len(unscoped) == 1, unscoped
+    eigh = [ln for ln in text.splitlines() if '"EighTpu"' in ln]
+    assert eigh and all(scope(ln)[-1] in ("dsfd.shrink", "dsfd.rotate")
+                        for ln in eigh)
+    found = {p for ln in text.splitlines() for p in scope(ln)}
+    assert {"dsfd.absorb", "dsfd.dump", "dsfd.rotate", "dsfd.shrink",
+            "dsfd.insert", "dsfd.expire", "dsfd.swap"} <= found

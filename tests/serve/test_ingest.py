@@ -427,9 +427,12 @@ def test_idle_step_resets_dispatch_latency():
     eng = _engine()
     _feed(eng, X, 1)
     eng.run()
-    assert eng.last_dispatch_s > 0.0
+    busy = eng.ticks()[-1]
+    assert busy["dispatch_end"] - busy["start"] > 0.0
     assert eng.step() == 0                       # idle poll
-    assert eng.last_dispatch_s == 0.0, \
+    idle = eng.ticks()[-1]
+    assert idle["step"] == busy["step"] + 1 and idle["rows"] == 0
+    assert np.isnan(idle["dispatch_start"]) and np.isnan(idle["dispatch_end"]), \
         "idle tick must not report the previous tick's dispatch latency"
 
 
