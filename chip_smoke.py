@@ -71,9 +71,9 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parent
 
 STREAMS = 32_768            # one-chip fleet size (compiled program ~12.3 GB)
-# The fleet update costs 1.8 ms per stream per tick on a v5e at a few
-# hundred streams and 2.7 ms at 32,768 (the batched ring append runs as
-# a serial loop over streams), so the phases that run for windows are
+# The fleet update cost 1.8 ms per stream per tick on a v5e at a few
+# hundred streams and 2.7 ms at 32,768 (measured while a snapshot dump
+# was a loop of ring appends), so the phases that run for windows are
 # cut to a stream count whose ticks fit the 20-minute limit of a run
 # with room to spare.  Widths are never cut.
 TICK_STREAMS = 256          # phases A, B, C (C was planned at 4,096)

@@ -5,7 +5,7 @@ Implementation notes (TPU/JAX adaptation — see DESIGN.md §3):
 
 * The paper's Python object queues become fixed-capacity **ring buffers** so
   the whole update is a static-shape pure function; expiry is timestamp
-  masking; the dump "while" loop is a bounded masked loop.
+  masking; a dump writes its rows into the ring in one masked store.
 * One engine implements all cadences:
     - ``mode="exact"``  — SVD every step (Algorithm 2 cadence).
     - ``mode="fast"``   — SVD when the 2ℓ buffer fills (shrink) or when the
@@ -162,20 +162,38 @@ def _ring_append(sk: SketchState, v, s, t) -> SketchState:
 
 def _dump_sorted_rows(sk: SketchState, rows, nrows, now, theta) -> SketchState:
     """Given SVD-sorted rows, dump every row with ‖row‖² ≥ θ into the ring
-    (Algorithm 2 lines 9-11), then compact the remaining rows to the top."""
+    (Algorithm 2 lines 9-11), then compact the remaining rows to the top.
+
+    The ndump appends are one masked store: dump j lands on slot
+    (snap_next + j) mod cap, and ndump ≤ m ≤ cap writes no slot twice, so
+    evictions are read from the ring as it stood before the store."""
     m = rows.shape[0]
+    cap = sk.snap_v.shape[0]
+    if m > cap:
+        raise ValueError(f"snapshot ring of {cap} slots cannot take a dump "
+                         f"of up to {m} rows")
     with jax.named_scope("dsfd.dump"):
         norms = jnp.sum(rows * rows, axis=1)
         # sorted ⇒ prefix
         ndump = jnp.sum((norms >= theta).astype(jnp.int32))
 
-        def body(j, sk):
-            def do(sk):
-                s = jnp.where(j == 0, sk.last_t + 1, now)
-                return _ring_append(sk, rows[j], s, now)
-            return jax.lax.cond(j < ndump, do, lambda sk: sk, sk)
-
-        sk = jax.lax.fori_loop(0, m, body, sk)
+        # j[c]: the index of the dump that lands on slot c
+        j = jnp.mod(jnp.arange(cap) - sk.snap_next, cap)
+        write = j < ndump
+        evicted = write & sk.snap_valid
+        cov = jnp.maximum(sk.cov_start,
+                          jnp.max(jnp.where(evicted, sk.snap_t + 1, _NEG)))
+        sk = sk._replace(
+            snap_v=jnp.where(write[:, None], rows[jnp.minimum(j, m - 1)],
+                             sk.snap_v),
+            snap_s=jnp.where(write, jnp.where(j == 0, sk.last_t + 1, now),
+                             sk.snap_s),
+            snap_t=jnp.where(write, now, sk.snap_t),
+            snap_valid=sk.snap_valid | write,
+            snap_next=sk.snap_next + ndump,
+            cov_start=cov,
+            last_t=jnp.where(ndump > 0, now, sk.last_t),
+        )
 
         kept = jnp.roll(rows, -ndump, axis=0)
         nkeep = jnp.maximum(nrows - ndump, 0)

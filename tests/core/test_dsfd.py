@@ -116,3 +116,122 @@ def test_modes_agree_roughly():
     scale = np.linalg.norm(g["exact"], 2)
     assert np.linalg.norm(g["fast"] - g["exact"], 2) <= 0.5 * scale + 1e-3
     assert np.linalg.norm(g["krylov"] - g["exact"], 2) <= 0.5 * scale + 1e-3
+
+
+def _loop_dump_sorted_rows(sk, rows, nrows, now, theta):
+    """The snapshot dump as a masked loop of ring appends: the oracle the
+    one-store ``_dump_sorted_rows`` must match bit for bit."""
+    import jax
+    from repro.core.dsfd import _ring_append
+
+    m = rows.shape[0]
+    with jax.named_scope("dsfd.dump"):
+        norms = jnp.sum(rows * rows, axis=1)
+        # sorted ⇒ prefix
+        ndump = jnp.sum((norms >= theta).astype(jnp.int32))
+
+        def body(j, sk):
+            def do(sk):
+                s = jnp.where(j == 0, sk.last_t + 1, now)
+                return _ring_append(sk, rows[j], s, now)
+            return jax.lax.cond(j < ndump, do, lambda sk: sk, sk)
+
+        sk = jax.lax.fori_loop(0, m, body, sk)
+
+        kept = jnp.roll(rows, -ndump, axis=0)
+        nkeep = jnp.maximum(nrows - ndump, 0)
+        kept = jnp.where(jnp.arange(m)[:, None] < nkeep, kept, 0.0)
+        sig1 = jnp.sum(kept[0] * kept[0])
+        return sk._replace(buf=kept, nbuf=nkeep.astype(jnp.int32),
+                           sig1=sig1)
+
+
+def _fleet_run(cfg, rows, thetas, swap_energy):
+    """(S, T, d) rows through the vmapped ``dsfd_update``, one θ a step."""
+    import jax
+    from repro.core.dsfd import dsfd_init, dsfd_update
+
+    ts = jnp.arange(1, rows.shape[1] + 1, dtype=jnp.int32)
+
+    def stream(rows):
+        def step(state, inp):
+            t, row, theta = inp
+            return dsfd_update(cfg, state, row, t, theta=theta,
+                               swap_energy=swap_energy), None
+        return jax.lax.scan(step, dsfd_init(cfg), (ts, rows, thetas))[0]
+
+    return jax.jit(jax.vmap(stream))(rows)
+
+
+_DUMP_CASES = ["d64-eps8", "d300-eps32", "small-ring-wraps", "ndump-0-and-m"]
+
+
+def _dump_case(name):
+    """(cfg, rows, θ per step, swap energy) of one exactness case."""
+    rng = np.random.default_rng(_DUMP_CASES.index(name))
+
+    def rows_with_drift(S, T, d, zero_share=0.0):
+        lead = rng.normal(size=(S, 1, d))
+        A = rng.normal(size=(S, T, d)) + 3.0 * lead
+        A /= np.linalg.norm(A, axis=2, keepdims=True)
+        A[rng.random((S, T)) < zero_share] = 0.0
+        return jnp.asarray(A, jnp.float32)
+
+    if name == "d64-eps8":
+        cfg = make_config(64, 1 / 8, 48)
+        rows = rows_with_drift(3, 160, 64, zero_share=0.1)
+        return cfg, rows, jnp.full((160,), 48 / 8, jnp.float32), None
+    if name == "d300-eps32":
+        cfg = make_config(300, 1 / 32, 96)
+        rows = rows_with_drift(2, 140, 300)
+        return cfg, rows, jnp.full((140,), 96 / 32, jnp.float32), None
+    if name == "small-ring-wraps":
+        # θ far below a row's energy and no swaps: the 12-slot ring wraps
+        # many times, every wrap evicting live snapshots
+        cfg = make_config(16, 1 / 4, 10**6, beta=1e9)
+        rows = rows_with_drift(3, 240, 16, zero_share=0.2)
+        return cfg, rows, jnp.full((240,), 0.5, jnp.float32), 1e9
+    if name == "ndump-0-and-m":
+        # exact mode: θ huge for m−1 steps (nothing dumps), then tiny on
+        # the step that fills the buffer, which dumps all m rows
+        cfg = make_config(16, 1 / 4, 10**6, mode="exact")
+        T = 4 * cfg.m
+        rows = jnp.asarray(rng.normal(size=(2, T, 16)), jnp.float32)
+        fill = (np.arange(1, T + 1) % cfg.m) == 0
+        return cfg, rows, jnp.asarray(np.where(fill, 1e-6, 1e30),
+                                      jnp.float32), 1e9
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("case", _DUMP_CASES)
+def test_ring_store_dump_matches_the_append_loop(monkeypatch, case):
+    """The one masked store of ``_dump_sorted_rows`` leaves every leaf of
+    the vmapped fleet state bit for bit as the loop of ring appends does."""
+    import jax
+    from repro.core import dsfd
+
+    cfg, rows, thetas, swap = _dump_case(case)
+    got = _fleet_run(cfg, rows, thetas, swap)
+    monkeypatch.setattr(dsfd, "_dump_sorted_rows", _loop_dump_sorted_rows)
+    want = _fleet_run(cfg, rows, thetas, swap)
+
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        assert np.array_equal(np.asarray(g), np.asarray(w)), path
+    appended = int(np.max(np.asarray(want.main.snap_next)))
+    assert appended > 0, "the case dumped nothing"
+    if case == "small-ring-wraps":
+        assert appended > 10 * cfg.cap
+    if case == "ndump-0-and-m":
+        assert appended == 4 * cfg.m
+
+
+def test_ring_too_small_for_a_dump_is_refused():
+    """A dump writes up to m = 2ℓ slots at once, so a ring of fewer slots
+    is refused at trace time rather than overwritten out of order."""
+    import dataclasses
+    from repro.core.dsfd import dsfd_init, dsfd_update
+
+    cfg = dataclasses.replace(make_config(16, 1 / 4, 64), cap=7)
+    with pytest.raises(ValueError, match="7 slots"):
+        dsfd_update(cfg, dsfd_init(cfg), jnp.ones((16,), jnp.float32), 1)

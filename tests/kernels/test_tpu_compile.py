@@ -15,6 +15,7 @@ this one file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -151,28 +152,45 @@ def test_four_chip_fleet_program_holds_a_quarter_per_chip(v5e_devices):
         f"more than its quarter {state_quarter / 1e9:.3f} GB")
 
 
-def test_update_program_for_v5e_keeps_the_dsfd_scopes(v5e_device):
+@pytest.fixture(scope="module")
+def v5e_update_text(v5e_device):
+    """HLO text of the engine-default fleet's ``update_block`` at 256
+    streams, compiled for the described v5e."""
+    fleet, state, rows = _smoke_fleet([v5e_device], 256)
+    ts = _spec((8,), jnp.int32, SingleDeviceSharding(v5e_device))
+    return fleet.update_block.lower(state, rows, ts).compile().as_text()
+
+
+def _dsfd_scopes(line):
+    op = re.search(r'op_name="([^"]*)"', line)
+    parts = op.group(1).split("/") if op else []
+    return [p for p in parts if p.startswith("dsfd.")]
+
+
+def test_update_program_for_v5e_keeps_the_dsfd_scopes(v5e_update_text):
     """The device trace's ops are attributed to DS-FD phases through the
     compiled program's ``op_name`` metadata: on the chip's compiler every
     loop of the update but the row scan itself, and every ``EighTpu`` of
     its SVDs, must still carry a ``dsfd.*`` scope."""
-    import re
-
-    fleet, state, rows = _smoke_fleet([v5e_device], 256)
-    ts = _spec((8,), jnp.int32, SingleDeviceSharding(v5e_device))
-    text = fleet.update_block.lower(state, rows, ts).compile().as_text()
-
-    def scope(line):
-        op = re.search(r'op_name="([^"]*)"', line)
-        parts = op.group(1).split("/") if op else []
-        return [p for p in parts if p.startswith("dsfd.")]
-
+    text = v5e_update_text
     loops = [ln for ln in text.splitlines() if " while(" in ln]
-    unscoped = [ln.split(" = ")[0].strip() for ln in loops if not scope(ln)]
+    unscoped = [ln.split(" = ")[0].strip() for ln in loops
+                if not _dsfd_scopes(ln)]
     assert len(loops) > 10 and len(unscoped) == 1, unscoped
     eigh = [ln for ln in text.splitlines() if '"EighTpu"' in ln]
-    assert eigh and all(scope(ln)[-1] in ("dsfd.shrink", "dsfd.rotate")
+    assert eigh and all(_dsfd_scopes(ln)[-1] in ("dsfd.shrink", "dsfd.rotate")
                         for ln in eigh)
-    found = {p for ln in text.splitlines() for p in scope(ln)}
+    found = {p for ln in text.splitlines() for p in _dsfd_scopes(ln)}
     assert {"dsfd.absorb", "dsfd.dump", "dsfd.rotate", "dsfd.shrink",
             "dsfd.insert", "dsfd.expire", "dsfd.swap"} <= found
+
+
+def test_update_program_for_v5e_dumps_without_a_loop(v5e_update_text):
+    """A snapshot dump is one masked store into the ring: no ``while`` of
+    the update program carries the ``dsfd.dump`` scope, while its ops are
+    still there for ``update_dump_ms.sat`` to read."""
+    lines = v5e_update_text.splitlines()
+    dump_loops = [ln.split(" = ")[0].strip() for ln in lines
+                  if " while(" in ln and "dsfd.dump" in _dsfd_scopes(ln)]
+    assert not dump_loops, dump_loops
+    assert any("dsfd.dump" in _dsfd_scopes(ln) for ln in lines)

@@ -182,7 +182,7 @@ def test_update_program_names_the_dsfd_phases():
     for scope in ("dsfd.absorb", "dsfd.insert", "dsfd.shrink",
                   "dsfd.rotate", "dsfd.dump", "dsfd.expire", "dsfd.swap"):
         assert scope in scopes, sorted(scopes)
-    # the dump loop runs inside absorb
+    # the dump runs inside absorb
     assert any("dsfd.absorb/" in n and "dsfd.dump" in n for n in names)
 
 
