@@ -358,7 +358,8 @@ def dsfd_update(cfg: DSFDConfig, state: DSFDState, row: jax.Array, now,
 
     ``theta`` defaults to εN = N/ℓ (Problem 1.1).  ``bypass`` enables the
     Seq-DS-FD heavy-row shortcut (Algorithm 6 lines 4-6): rows with
-    ‖a‖² ≥ θ go straight into both snapshot queues.
+    ‖a‖² ≥ θ go straight into both snapshot queues, under the
+    ``dsfd.bypass`` named scope.
     """
     now = jnp.asarray(now, jnp.int32)
     theta = jnp.asarray(
@@ -397,8 +398,9 @@ def dsfd_update(cfg: DSFDConfig, state: DSFDState, row: jax.Array, now,
         if bypass:
             def heavy(ma):
                 main, aux = ma
-                return (_ring_append(main, row, main.last_t + 1, now),
-                        _ring_append(aux, row, aux.last_t + 1, now))
+                with jax.named_scope("dsfd.bypass"):
+                    return (_ring_append(main, row, main.last_t + 1, now),
+                            _ring_append(aux, row, aux.last_t + 1, now))
 
             main, aux = jax.lax.cond(
                 e >= theta, heavy,

@@ -80,16 +80,21 @@ def layered_update(cfg: LayeredConfig, state, row: jax.Array, now):
 
 
 def layered_covered(cfg: LayeredConfig, state, now) -> jax.Array:
-    """Per-layer bool: does (queue ∪ residual) span the window [now−N+1, now]?"""
+    """Per-layer bool: does (queue ∪ residual) span the window
+    [max(now−N+1, 1), now]?  Timestamps start at 1, so before a window has
+    filled it is the whole stream so far, which a layer that has lost
+    nothing since the stream's start (``cov_start`` 1) covers."""
     now = jnp.asarray(now, jnp.int32)
-    return state.main.cov_start <= now - cfg.base.window + 1
+    return state.main.cov_start <= jnp.maximum(now - cfg.base.window + 1, 1)
 
 
 def layered_select(cfg: LayeredConfig, state, now) -> jax.Array:
-    """Index of the lowest covered layer (Algorithm 7 line 1)."""
-    cov = layered_covered(cfg, state, now)
-    idx = jnp.arange(cfg.levels)
-    return jnp.min(jnp.where(cov, idx, cfg.levels - 1))
+    """Index of the lowest covered layer (Algorithm 7 line 1); the top
+    layer when none covers."""
+    with jax.named_scope("dsfd.select"):
+        cov = layered_covered(cfg, state, now)
+        idx = jnp.arange(cfg.levels)
+        return jnp.min(jnp.where(cov, idx, cfg.levels - 1))
 
 
 def layered_query_rows(cfg: LayeredConfig, state, now) -> jax.Array:

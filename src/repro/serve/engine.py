@@ -25,13 +25,14 @@ import dataclasses
 import time
 import warnings
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.core.seq_dsfd import LayeredConfig
 from repro.models import api
 from repro.serve import trace
 from repro.serve.serve_step import build_decode_step, build_prefill_step
@@ -715,7 +716,7 @@ class SketchFleetEngine:
         profiler trace as ``engine.*`` annotations while one runs."""
         return self.log.records()
 
-    def counters(self) -> Dict[str, Optional[int]]:
+    def counters(self) -> Dict[str, Any]:
         """Device counters since the previous call (or since the engine
         was built or restored), from one small program over the fleet
         state; nothing is counted on the tick path.
@@ -725,19 +726,29 @@ class SketchFleetEngine:
         eviction; ``swaps``: main/aux swaps (see
         ``repro.serve.trace.census_diff`` for when a read must come at
         least once per swap period to be exact); these are ``None`` for a
-        variant without snapshot rings.  ``peak_bytes_in_use``: the
+        variant without snapshot rings.  A layered variant (``seq-dsfd``,
+        ``time-dsfd``) also gives ``dumped_by_layer``, ``left_by_layer``
+        and ``swaps_by_layer``, lists over its layers that sum to the
+        totals, and ``query_layer``: for each layer, how many streams
+        Algorithm 7 selects it for at the engine's clock; these four are
+        ``None`` for any other variant.  ``peak_bytes_in_use``: the
         largest device's peak (``None`` where the runtime keeps no
         statistics), beside ``update_*_bytes`` from the update program's
         ``memory_analysis()``."""
-        out: Dict[str, Optional[int]] = dict.fromkeys(
-            ("dumped", "left", "swaps"))
+        out: Dict[str, Any] = dict.fromkeys(
+            ("dumped", "left", "swaps", "dumped_by_layer", "left_by_layer",
+             "swaps_by_layer", "query_layer"))
         if trace.has_census(self.state):
             now = trace.census(self.state)
             before = (trace.initial_census(self.state)
                       if self._census is None else self._census)
-            out.update({k: int(v) for k, v in
-                        trace.census_diff(before, now).items()})
+            out.update({k: None if v is None else np.asarray(v).tolist()
+                        for k, v in trace.census_diff(before, now).items()})
             self._census = now
+        cfg = self.base.meta.get("cfg")
+        if isinstance(cfg, LayeredConfig):
+            out["query_layer"] = np.asarray(trace.query_layers(
+                cfg, self.state, self.t)).tolist()
         devices = self.fleet.meta["slab_sharding"].device_set
         stats = [d.memory_stats() for d in devices]
         out["peak_bytes_in_use"] = (

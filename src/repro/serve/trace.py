@@ -31,9 +31,11 @@ Two records are kept of the same spans:
 The device side carries named scopes instead (``jax.named_scope``, in
 the compiled programs' ``op_name`` metadata): ``dsfd.expire``,
 ``dsfd.swap`` and ``dsfd.absorb`` (inside it ``dsfd.insert``,
-``dsfd.shrink``, ``dsfd.rotate``, ``dsfd.dump``, ``dsfd.krylov``) in the
-update program, ``fleet.score`` and ``fleet.query`` in the scoring and
-cohort-merge programs.
+``dsfd.shrink``, ``dsfd.rotate``, ``dsfd.dump``, ``dsfd.krylov``, and in
+the layered variants ``dsfd.bypass``) in the update program,
+``dsfd.select`` (Algorithm 7's layer choice) in the layered queries,
+``fleet.score`` and ``fleet.query`` in the scoring and cohort-merge
+programs.
 
 ``census`` and ``census_diff`` are the counters
 ``SketchFleetEngine.counters()`` reads from the fleet state on demand.
@@ -42,12 +44,15 @@ cohort-merge programs.
 from __future__ import annotations
 
 import collections
+import functools
 import time
 from typing import Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.core.seq_dsfd import layered_select
 
 PHASES = ("next_slab", "score", "dispatch", "tree_advance", "score_observe",
           "history", "prefetch", "wait")
@@ -197,9 +202,11 @@ def census(state) -> Dict[str, jax.Array]:
 
 
 @jax.jit
-def census_diff(before, after) -> Dict[str, jax.Array]:
+def census_diff(before, after) -> Dict[str, Optional[jax.Array]]:
     """Fleet totals between two censuses: snapshots dumped, snapshots
-    that left a ring by expiry or eviction, and main/aux swaps.
+    that left a ring by expiry or eviction, and main/aux swaps; for a
+    layered fleet (censuses of shape (streams, layers)) also the same
+    three summed over streams by layer (``*_by_layer``, else ``None``).
 
     A swap shows as a new auxiliary start; it promotes the auxiliary to
     main and starts a fresh auxiliary.  With more than one swap of a
@@ -218,8 +225,21 @@ def census_diff(before, after) -> Dict[str, jax.Array]:
     left = (main_live0 + dm - after["main_live"]
             + aux_live0 + da - after["aux_live"])
     total = lambda x: jnp.sum(x, dtype=jnp.int32)            # noqa: E731
-    return {"dumped": total(dm + da), "left": total(left),
-            "swaps": total(swapped)}
+    counts = {"dumped": dm + da, "left": left, "swaps": swapped}
+    out = {k: total(v) for k, v in counts.items()}
+    layered = swapped.ndim == 2
+    for k, v in counts.items():
+        out[f"{k}_by_layer"] = (jnp.sum(v, axis=0, dtype=jnp.int32)
+                                if layered else None)
+    return out
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def query_layers(cfg, state, now) -> jax.Array:
+    """For each layer of a layered fleet (``cfg`` its ``LayeredConfig``),
+    how many streams Algorithm 7 selects it for at clock ``now``."""
+    chosen = jax.vmap(lambda s: layered_select(cfg, s, now))(state)
+    return jnp.bincount(chosen, length=cfg.levels)
 
 
 def has_census(state) -> bool:

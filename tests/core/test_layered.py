@@ -108,3 +108,63 @@ def test_heavy_row_bypass_is_lossless():
     G = A.T @ A
     err = float(cova_error_gram(jnp.asarray(G), jnp.asarray(B)))
     assert err <= BETA * eps * np.sum(A * A)
+
+
+# Algorithm 7's cover test: the window is [max(now − N + 1, 1), now]
+
+FINDING_COV = [416, 415, 379, 440, 406, 341, 231] + [1] * 13
+
+
+def _with_cov_start(cfg, cov):
+    state = layered_init(cfg)
+    main = state.main._replace(cov_start=jnp.asarray(cov, jnp.int32))
+    return state._replace(main=main)
+
+
+def test_select_before_the_window_fills_takes_the_lowest_lossless_layer():
+    """RAIL's shape (d=500, ε=1/8, N=50,000, R=64: 20 layers) at t=480:
+    layers 0-6 have lost rows, layers 7-19 none.  The window is the whole
+    stream so far, which layer 7 covers; the top layer is not needed."""
+    cfg = make_time_config(500, 1 / 8, 50_000, 64.0)
+    assert cfg.levels == 20
+    state = _with_cov_start(cfg, FINDING_COV)
+    assert int(layered_select(cfg, state, 480)) == 7
+    # a layer that lost nothing covers at every clock before N
+    assert int(layered_select(cfg, state, 50_000)) == 7
+    # nothing lossless: the top layer, as before
+    assert int(layered_select(cfg, _with_cov_start(cfg, [2] * 20), 480)) == 19
+
+
+@pytest.mark.parametrize("now", [50_001, 50_340, 50_416, 50_500, 90_000])
+def test_select_after_the_window_fills_is_unchanged(now):
+    """Past N the window starts at now − N + 1 > 1: the lowest layer
+    with cov_start ≤ now − N + 1, else the top one."""
+    cfg = make_time_config(500, 1 / 8, 50_000, 64.0)
+    cov = np.asarray(FINDING_COV) + np.arange(20) * 997
+    state = _with_cov_start(cfg, cov)
+    ok = np.flatnonzero(cov <= now - 50_000 + 1)
+    want = int(ok[0]) if ok.size else 19
+    assert int(layered_select(cfg, state, now)) == want
+
+
+def test_select_on_a_stream_before_its_window_fills():
+    """Heavy rows fill and evict the low layers' rings long before N: the
+    query answers from the lowest layer whose cov_start is still 1, and
+    that layer's answer meets the bound over every row so far."""
+    d, N, eps, R = 8, 4096, 1 / 4, 64.0
+    cfg = make_time_config(d, eps, N, R)
+    rng = np.random.default_rng(3)
+    n = 300
+    A = rng.normal(size=(n, d)).astype(np.float32)
+    A /= np.linalg.norm(A, axis=1, keepdims=True)
+    A *= np.sqrt(rng.uniform(1.0, 16.0, size=(n, 1))).astype(np.float32)
+    ts = np.arange(1, n + 1)
+    state, _ = layered_run_stream(cfg, jnp.asarray(A), jnp.asarray(ts))
+    cov = np.asarray(state.main.cov_start)
+    assert cov[0] > 1 and (cov == 1).any()
+    j = int(layered_select(cfg, state, n))
+    assert j == int(np.flatnonzero(cov == 1)[0]) < cfg.levels - 1
+    from repro.core.seq_dsfd import layered_query_rows
+    B = np.asarray(layered_query_rows(cfg, state, n))
+    err = float(cova_error_gram(jnp.asarray(A.T @ A), jnp.asarray(B)))
+    assert err <= BETA * eps * np.sum(A * A)

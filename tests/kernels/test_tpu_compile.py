@@ -194,3 +194,30 @@ def test_update_program_for_v5e_dumps_without_a_loop(v5e_update_text):
                   if " while(" in ln and "dsfd.dump" in _dsfd_scopes(ln)]
     assert not dump_loops, dump_loops
     assert any("dsfd.dump" in _dsfd_scopes(ln) for ln in lines)
+
+
+def test_time_dsfd_fleet_program_holds_the_bypass_and_fits_v5e(v5e_device):
+    """The rail-time-d500 cell's fleet update: Time-DS-FD at RAIL's widths
+    (d=500, ε=1/8, N=50,000, the engine's R=64: 20 layers) over 32
+    streams.  Its heavy-row bypass must carry the ``dsfd.bypass`` scope
+    for ``update_bypass_ms.sat``, and the program must fit a v5e's HBM:
+    temporaries are about six times the state at 20 layers."""
+    from repro.sketch.api import make_sketch, shard_streams
+
+    streams = 32
+    sk = make_sketch("time-dsfd", d=500, eps=1 / 8, window=50_000)
+    assert sk.meta["cfg"].levels == 20
+    fleet = shard_streams(sk, streams, Mesh(np.array([v5e_device]),
+                                            ("streams",)))
+    sharding = fleet.meta["slab_sharding"]
+    state = jax.tree.map(lambda a: _spec(a.shape, a.dtype, sharding),
+                         jax.eval_shape(lambda: fleet.init()))
+    rows = _spec((streams, 8, 500), jnp.float32, sharding)
+    ts = _spec((8,), jnp.int32, SingleDeviceSharding(v5e_device))
+    compiled = jax.jit(fleet.update_block).lower(state, rows, ts).compile()
+    text = compiled.as_text()
+    assert any("dsfd.bypass" in _dsfd_scopes(ln) for ln in text.splitlines())
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < V5E_HBM_BYTES, f"{total / 1e9:.2f} GB"

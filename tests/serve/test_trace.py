@@ -195,3 +195,66 @@ def test_score_and_cohort_merge_programs_are_named():
     one = jax.tree.map(lambda x: x[0], eng.state)
     merge = eng.tree._jmerge.lower(one, one, 3).compile().as_text()
     assert "fleet.query" in merge
+
+
+def _time_engine():
+    return SketchFleetEngine("time-dsfd", d=D, streams=S, eps=1 / 2,
+                             window=64, block=BLOCK, R=16.0)
+
+
+def _time_rows(seed):
+    g = np.random.default_rng(seed)
+    X = _unit_rows(S * BLOCK, seed) * np.sqrt(
+        g.uniform(1.0, 16.0, size=(S * BLOCK, 1))).astype(np.float32)
+    X[g.random(S * BLOCK) < 0.5] = 0.0
+    return X
+
+
+def test_layered_counters_by_layer_sum_to_the_totals():
+    eng = _time_engine()
+    levels = eng.base.meta["cfg"].levels
+    seen = {"dumped": 0, "left": 0, "swaps": 0}
+    for k in range(24):
+        _full_tick(eng, _time_rows(k))
+        got = eng.counters()
+        for key in seen:
+            by = got[f"{key}_by_layer"]
+            assert len(by) == levels and sum(by) == got[key], (key, got)
+            seen[key] += got[key]
+    # heavy rows land in the low layers' rings, which evict; swaps happen
+    assert all(v > 0 for v in seen.values()), seen
+
+
+def test_query_layer_counts_algorithm_7_choice_per_stream():
+    from repro.core.seq_dsfd import layered_select
+
+    eng = _time_engine()
+    cfg = eng.base.meta["cfg"]
+    for k in range(10):
+        _full_tick(eng, _time_rows(100 + k))
+    chosen = [int(layered_select(cfg, jax.tree.map(lambda x: x[s],
+                                                   eng.state), eng.t))
+              for s in range(S)]
+    got = eng.counters()["query_layer"]
+    assert got == np.bincount(chosen, minlength=cfg.levels).tolist()
+    assert sum(got) == S
+
+
+def test_plain_dsfd_has_no_layer_counters():
+    eng = _engine()
+    _full_tick(eng, _unit_rows(S * BLOCK))
+    got = eng.counters()
+    for key in ("dumped_by_layer", "left_by_layer", "swaps_by_layer",
+                "query_layer"):
+        assert got[key] is None, key
+    assert isinstance(got["dumped"], int)
+
+
+def test_layered_programs_name_the_bypass_and_the_selection():
+    """Time-DS-FD's heavy-row bypass in the update program and Algorithm
+    7's layer choice in the query program carry their named scopes."""
+    eng = _time_engine()
+    assert "dsfd.bypass" in eng.compiled_update().as_text()
+    one = jax.tree.map(lambda x: x[0], eng.state)
+    query = jax.jit(eng.base.query).lower(one, 8).compile().as_text()
+    assert "dsfd.select" in query
