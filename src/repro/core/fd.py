@@ -46,16 +46,32 @@ def fd_init(ell: int, d: int, dtype=jnp.float32) -> FDState:
 
 
 def _svd_rows(buf: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """SVD of the buffer; returns (rows = Σ·Vᵀ padded to buf.shape, σ²)."""
+    """Rows σᵢ·vᵢᵀ of the buffer, sorted by σ² descending and padded to
+    ``buf.shape``, and σ² (clamped at 0).
+
+    FD needs only the spectrum and the rotated rows, so they come from one
+    ``eigh`` of the smaller Gram matrix, chosen by the static shape: for
+    m ≤ d, K = BBᵀ = UΛUᵀ and the rows are UᵀB; for d < m, K = BᵀB = VΛVᵀ
+    and the rows are √λ·Vᵀ.  Either way rowsᵀrows = BᵀB.  A general SVD
+    would lower on the TPU to a QR and polar-decomposition loops around
+    an ``eigh`` of the same size.  The matmuls run at full float32
+    precision: the TPU's default would take bf16 passes."""
     m, d = buf.shape
-    # full_matrices=False: S has r = min(m, d) entries, Vt is (r, d).
-    _, s, vt = jnp.linalg.svd(buf, full_matrices=False)
-    rows = s[:, None] * vt                               # (r, d), sorted desc
-    if rows.shape[0] < m:                                # pad when d < m
-        rows = jnp.concatenate(
-            [rows, jnp.zeros((m - rows.shape[0], d), buf.dtype)], axis=0)
-        s = jnp.concatenate([s, jnp.zeros((m - s.shape[0],), s.dtype)])
-    return rows, s * s
+    hi = jax.lax.Precision.HIGHEST
+    if m <= d:
+        lam, u = jnp.linalg.eigh(jnp.matmul(buf, buf.T, precision=hi))
+        s2 = jnp.maximum(lam[::-1], 0.0)                 # eigh: ascending
+        rows = jnp.matmul(u[:, ::-1].T, buf, precision=hi)
+    else:
+        lam, v = jnp.linalg.eigh(jnp.matmul(buf.T, buf, precision=hi))
+        s2 = jnp.pad(jnp.maximum(lam[::-1], 0.0), (0, m - d))
+        rows = jnp.sqrt(s2)[:, None] * jnp.pad(v[:, ::-1].T,
+                                               ((0, m - d), (0, 0)))
+    # eigh fixes each vector only up to sign, and its pick can flip with
+    # the last bits of K, so that two programs over one stream would part;
+    # the entry of largest magnitude of every row is made positive
+    flip = -jnp.min(rows, axis=1) > jnp.max(rows, axis=1)
+    return jnp.where(flip[:, None], -rows, rows), s2
 
 
 def fd_rotate(buf: jax.Array) -> Tuple[jax.Array, jax.Array]:

@@ -167,19 +167,34 @@ def _dsfd_scopes(line):
     return [p for p in parts if p.startswith("dsfd.")]
 
 
+def _svd_structure(text):
+    """FD's rotate and shrink are one Gram ``eigh`` a sketch a row, with no
+    QR, Cholesky or polar-decomposition loop: the row scan is the program's
+    one unscoped ``while``, no ``while``, ``QrDecompositionBlock`` or
+    ``Cholesky`` carries ``dsfd.shrink`` or ``dsfd.rotate``, and there are
+    exactly two ``EighTpu`` (main and aux), each under one of them."""
+    lines = text.splitlines()
+    loops = [ln for ln in lines if " while(" in ln]
+    unscoped = [ln.split(" = ")[0].strip() for ln in loops
+                if not _dsfd_scopes(ln)]
+    assert len(unscoped) == 1, unscoped
+    svd = {"dsfd.shrink", "dsfd.rotate"}
+    heavy = [ln.split(" = ")[0].strip() for ln in lines
+             if (" while(" in ln or '"QrDecompositionBlock"' in ln
+                 or '"Cholesky"' in ln) and svd & set(_dsfd_scopes(ln))]
+    assert not heavy, heavy
+    eigh = [ln for ln in lines if '"EighTpu"' in ln]
+    assert len(eigh) == 2 and all(_dsfd_scopes(ln)[-1] in svd
+                                  for ln in eigh), eigh
+
+
 def test_update_program_for_v5e_keeps_the_dsfd_scopes(v5e_update_text):
     """The device trace's ops are attributed to DS-FD phases through the
     compiled program's ``op_name`` metadata: on the chip's compiler every
     loop of the update but the row scan itself, and every ``EighTpu`` of
-    its SVDs, must still carry a ``dsfd.*`` scope."""
+    its rotates and shrinks, must still carry a ``dsfd.*`` scope."""
     text = v5e_update_text
-    loops = [ln for ln in text.splitlines() if " while(" in ln]
-    unscoped = [ln.split(" = ")[0].strip() for ln in loops
-                if not _dsfd_scopes(ln)]
-    assert len(loops) > 10 and len(unscoped) == 1, unscoped
-    eigh = [ln for ln in text.splitlines() if '"EighTpu"' in ln]
-    assert eigh and all(_dsfd_scopes(ln)[-1] in ("dsfd.shrink", "dsfd.rotate")
-                        for ln in eigh)
+    _svd_structure(text)
     found = {p for ln in text.splitlines() for p in _dsfd_scopes(ln)}
     assert {"dsfd.absorb", "dsfd.dump", "dsfd.rotate", "dsfd.shrink",
             "dsfd.insert", "dsfd.expire", "dsfd.swap"} <= found
@@ -200,8 +215,10 @@ def test_time_dsfd_fleet_program_holds_the_bypass_and_fits_v5e(v5e_device):
     """The rail-time-d500 cell's fleet update: Time-DS-FD at RAIL's widths
     (d=500, ε=1/8, N=50,000, the engine's R=64: 20 layers) over 32
     streams.  Its heavy-row bypass must carry the ``dsfd.bypass`` scope
-    for ``update_bypass_ms.sat``, and the program must fit a v5e's HBM:
-    temporaries are about six times the state at 20 layers."""
+    for ``update_bypass_ms.sat``, its layers' rotates and shrinks must be
+    the same two Gram ``eigh`` as the plain update's, and the program must
+    fit a v5e's HBM: temporaries are about four times the state at 20
+    layers."""
     from repro.sketch.api import make_sketch, shard_streams
 
     streams = 32
@@ -217,6 +234,7 @@ def test_time_dsfd_fleet_program_holds_the_bypass_and_fits_v5e(v5e_device):
     compiled = jax.jit(fleet.update_block).lower(state, rows, ts).compile()
     text = compiled.as_text()
     assert any("dsfd.bypass" in _dsfd_scopes(ln) for ln in text.splitlines())
+    _svd_structure(text)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
