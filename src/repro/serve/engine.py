@@ -1,28 +1,16 @@
-"""Batched serving engines.
+"""The sketch serving engine.
 
-Two serving paths live here:
-
-* ``ServeEngine`` — fixed-slot continuous batching over the jit'd
-  prefill/decode steps.  B slots run in lockstep (one decode_step per tick
-  advances every active slot); finished or empty slots are refilled by
-  prefilling the next queued request and splicing its caches into the batch
-  at the slot index.  This is the vLLM-style "continuous batching lite"
-  that a fixed-shape jit world supports: no recompilation at runtime —
-  prefill is compiled per bucketed prompt length, decode once.
-
-* ``SketchFleetEngine`` — the fleet-backed sketch serving path: S per-user
-  sliding-window sketches advanced as ONE SPMD program
-  (``shard_streams``), with per-user queries and cross-shard ``merge``
-  aggregation for global-window queries.  Rows are admitted through the
-  ingest subsystem (``repro.serve.ingest``): a bounded, validating
-  admission queue feeding a double-buffered slab pipeline that packs and
-  prefetches slab k+1 while the device consumes slab k.
+``SketchFleetEngine`` is the fleet-backed sketch serving path: S per-user
+sliding-window sketches advanced as ONE SPMD program (``shard_streams``),
+with per-user queries and cross-shard ``merge`` aggregation for
+global-window queries.  Rows are admitted through the ingest subsystem
+(``repro.serve.ingest``): a bounded, validating admission queue feeding a
+double-buffered slab pipeline that packs and prefetches slab k+1 while
+the device consumes slab k.
 """
 
 from __future__ import annotations
 
-import dataclasses
-import time
 import warnings
 from collections import deque
 from typing import Any, Dict, List, Optional
@@ -31,130 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig
 from repro.core.seq_dsfd import LayeredConfig
-from repro.models import api
 from repro.serve import trace
-from repro.serve.serve_step import build_decode_step, build_prefill_step
-
-
-@dataclasses.dataclass
-class Request:
-    uid: int
-    prompt: np.ndarray                 # (len,) int32
-    max_new: int = 16
-    eos_id: Optional[int] = None
-    # filled by the engine:
-    out_tokens: Optional[List[int]] = None
-    latency_s: float = 0.0
-    t_submit: float = 0.0
-
-
-@dataclasses.dataclass(frozen=True)
-class EngineConfig:
-    slots: int = 4                     # decode batch width
-    s_max: int = 256                   # cache capacity
-    prefill_buckets: tuple = (32, 64, 128)
-    temperature: float = 0.0
-
-
-class ServeEngine:
-    """Single-host engine over jit'd steps (the multi-pod serve path jits
-    the same fns with mesh shardings — see launch/serve.py)."""
-
-    def __init__(self, cfg: ModelConfig, params, ecfg: EngineConfig,
-                 dtype=jnp.float32):
-        self.cfg = cfg
-        self.ecfg = ecfg
-        self.params = params
-        self.dtype = dtype
-        self.queue: deque = deque()
-        self.done: Dict[int, Request] = {}
-        self.slot_req: List[Optional[Request]] = [None] * ecfg.slots
-        self.slot_left: np.ndarray = np.zeros(ecfg.slots, np.int32)
-        self.tokens = jnp.zeros((ecfg.slots, 1), jnp.int32)
-        self.caches = api.init_cache(cfg, ecfg.slots, ecfg.s_max, dtype)
-        self._decode = jax.jit(build_decode_step(
-            cfg, temperature=ecfg.temperature), donate_argnums=(2,))
-        self._prefill_b1 = jax.jit(build_prefill_step(cfg))
-        self.ticks = 0
-
-    # -- admission ---------------------------------------------------------
-
-    def submit(self, req: Request) -> None:
-        b_max = max(self.ecfg.prefill_buckets)
-        if len(req.prompt) > b_max:
-            raise ValueError(
-                f"prompt of {len(req.prompt)} tokens exceeds the largest "
-                f"prefill bucket ({b_max}); admitting it would silently "
-                f"drop all but the last {b_max} tokens — chunk the prompt "
-                "or enlarge EngineConfig.prefill_buckets")
-        req.t_submit = time.perf_counter()   # latency base: monotonic
-        req.out_tokens = []
-        self.queue.append(req)
-
-    def _bucket(self, n: int) -> int:
-        for b in self.ecfg.prefill_buckets:
-            if n <= b:
-                return b
-        # unreachable through submit(), which rejects over-long prompts
-        raise ValueError(
-            f"no prefill bucket holds {n} tokens "
-            f"(buckets={self.ecfg.prefill_buckets})")
-
-    def _admit(self, slot: int, req: Request) -> None:
-        b = self._bucket(len(req.prompt))
-        prompt = np.zeros((1, b), np.int32)
-        prompt[0, -len(req.prompt):] = req.prompt
-        tok, caches1 = self._prefill_b1(self.params,
-                                        {"tokens": jnp.asarray(prompt)})
-        # splice the single-request caches into slot `slot`
-        self.caches = _splice_caches(self.cfg, self.caches, caches1, slot,
-                                     self.ecfg.s_max)
-        self.tokens = self.tokens.at[slot].set(tok[0])
-        self.slot_req[slot] = req
-        self.slot_left[slot] = req.max_new
-        req.out_tokens.append(int(tok[0, 0]))
-
-    # -- main loop ----------------------------------------------------------
-
-    def step(self) -> None:
-        """One engine tick: refill slots, one decode step, harvest."""
-        for s in range(self.ecfg.slots):
-            if self.slot_req[s] is None and self.queue:
-                self._admit(s, self.queue.popleft())
-        if all(r is None for r in self.slot_req):
-            return
-        self.tokens, self.caches = self._decode(self.params, self.tokens,
-                                                self.caches)
-        self.ticks += 1
-        toks = np.asarray(self.tokens[:, 0])
-        for s, req in enumerate(self.slot_req):
-            if req is None:
-                continue
-            req.out_tokens.append(int(toks[s]))
-            self.slot_left[s] -= 1
-            hit_eos = req.eos_id is not None and toks[s] == req.eos_id
-            if self.slot_left[s] <= 0 or hit_eos:
-                req.latency_s = time.perf_counter() - req.t_submit
-                self.done[req.uid] = req
-                self.slot_req[s] = None
-
-    def run(self, max_ticks: int = 10_000) -> Dict[int, Request]:
-        # budget THIS call, not the engine's lifetime: self.ticks is
-        # cumulative, so comparing it to max_ticks would make run() a
-        # permanent no-op once a long-lived engine crosses the budget
-        t0 = self.ticks
-        while (self.queue or any(r is not None for r in self.slot_req)) \
-                and self.ticks - t0 < max_ticks:
-            self.step()
-        left = len(self.queue) + sum(r is not None for r in self.slot_req)
-        if left:
-            warnings.warn(
-                f"ServeEngine.run() exhausted max_ticks={max_ticks} with "
-                f"{left} request(s) unfinished — `done` is incomplete",
-                RuntimeWarning, stacklevel=2)
-        return self.done
 
 
 class SketchFleetEngine:
@@ -276,7 +142,7 @@ class SketchFleetEngine:
         # through train/checkpoint.py) instead of discarding it —
         # query_interval(cohort, t1, t2) then answers any historical
         # interval.  Each tick pays one host copy of the slab for the
-        # retirement path (opt-in; see benchmarks/fleet_throughput.py).
+        # retirement path (opt-in).
         self.history = None
         if history:
             from repro.sketch.history import (HistoryPlane,
@@ -919,36 +785,3 @@ def _score_aux_slice(aux: Dict[str, np.ndarray], lo: int,
     for base in ScorePlane.KEYS:
         out.setdefault(base, cold[base])
     return out
-
-
-def _splice_caches(cfg: ModelConfig, big, one, slot: int, s_max: int):
-    """Insert a batch-1 prefill cache into batch slot `slot` of the engine
-    cache, left-aligned into the s_max-long buffers where seq-shaped.
-
-    Left alignment is the decode-step convention: valid cache entries
-    occupy positions ``[0, length)`` and ``kv_cache_append`` writes the
-    next token at index ``length`` (``decode_attention`` masks
-    ``kpos < length``), so a b-token prefill cache lands at ``[0, b)``
-    with zero-padding *after* it and ``length = b`` picks up exactly where
-    prefill stopped.  Right-aligning the data into ``[s_max-b, s_max)``
-    would desynchronize it from the write position.  (The token-level
-    right-alignment of a short prompt *within* its prefill bucket in
-    ``_admit`` is a separate, upstream padding choice.)"""
-
-    def leaf(b, o):
-        if b.ndim == 0 or o.shape[0] != b.shape[0]:
-            return b
-        # layer-stacked leaves: dim0 = layers, dim1 = batch
-        if b.ndim >= 2 and o.shape[1] == 1 and b.shape[2:] != o.shape[2:]:
-            # seq-capacity mismatch (prefill len < s_max): left-align —
-            # pad zeros AFTER the cache so entry i stays at position i
-            pad = [(0, 0)] * o.ndim
-            pad[2] = (0, b.shape[2] - o.shape[2]) if b.ndim > 2 else (0, 0)
-            o = jnp.pad(o, pad)
-        if b.ndim >= 2 and o.shape[1] == 1:
-            return b.at[:, slot:slot + 1].set(o.astype(b.dtype))
-        if b.ndim == 1:                          # per-layer lengths
-            return o
-        return b
-
-    return jax.tree.map(leaf, big, one)

@@ -1,9 +1,10 @@
-"""DS-FD integrated into distributed training (DESIGN.md §2b):
+"""Sliding-window sketches behind one protocol, lifted into fleets:
 
 * ``api``      — the unified ``SlidingSketch`` protocol + registry: every
   sketch variant (DS-FD family and baselines) behind one
-  init/update/update_block/query_rows/query/space/merge contract, with
-  ``vmap_streams`` / ``shard_streams`` for fleet-scale serving.
+  init/update/update_block/query_rows/query/space/merge contract, and the
+  one fleet lift (``vmap_streams`` / ``shard_streams``) for fleet-scale
+  serving.
 * ``query``    — the fleet query plane: ``Cohort`` algebra (unions of
   stream ranges) + ``AggTree`` cached merge trees; ``query_cohort``
   answers aggregate queries over any cohort in O(log S) warm node merges

@@ -39,26 +39,27 @@ protocol method    paper mapping
 
 JAX-backed variants (``"fd"``, ``"dsfd"``, ``"seq-dsfd"``, ``"time-dsfd"``)
 are pure functions over pytree states, so they compose with ``jax.jit`` /
-``lax.scan`` / ``jax.vmap``:  ``vmap_streams(sk, S)`` lifts a sketch to S
-independent streams updated in one fused XLA program (the serving-scale
-path).  The numpy baselines (``"lmfd"``, ``"difd"``, ``"swr"``, ``"swor"``)
-satisfy the same protocol through a host-side adapter whose "state" is the
-mutable python object itself (returned back from ``update`` so call sites
-are written identically; host ``merge`` may likewise mutate and return its
-first argument).
+``lax.scan`` / ``jax.vmap``.  The numpy baselines (``"lmfd"``, ``"difd"``,
+``"swr"``, ``"swor"``) satisfy the same protocol through a host-side
+adapter whose "state" is the mutable python object itself (returned back
+from ``update`` so call sites are written identically; host ``merge`` may
+likewise mutate and return its first argument).
 
-Fleet scale: ``vmap_streams(sk, S)`` fuses S independent per-user streams
-into one XLA program on one device; ``shard_streams(sk, S, mesh)`` lays the
-same fleet out over every device of a mesh via ``shard_map`` (S must divide
-by the device count), so S × n_devices-scale fleets update as one SPMD
-program with zero cross-device traffic on the hot path.  Aggregate queries
-go through the **query plane** (``repro.sketch.query``):
-``query_cohort(fleet, state, cohort, t)`` answers any union of stream
-ranges (a ``Cohort``) with ONE merged base-variant sketch, served from the
-fleet's cached ``AggTree`` — a segment tree of partial merges whose warm
-queries cost O(log S) node merges instead of the O(S) from-scratch
-reduction.  ``merge_streams(fleet, state, t)`` survives as a deprecated
-alias for ``query_cohort(fleet, state, ALL, t)``.
+Fleet scale: one lift turns a JAX-backed sketch into a fleet of S
+independent per-user streams.  ``vmap_streams(sk, S)`` places it on one
+device; ``shard_streams(sk, S, mesh)`` splits the streams over every
+device of a mesh (S must divide by the device count), and with a
+``topology`` this process holds only its own stream range.  Either way
+``update_block`` is one jitted program per tick (``shard_map``'d when
+there is a mesh), with no cross-device traffic on the hot path.
+Aggregate queries go through the **query plane**
+(``repro.sketch.query``): ``query_cohort(fleet, state, cohort, t)``
+answers any union of stream ranges (a ``Cohort``) with ONE merged
+base-variant sketch, served from the fleet's cached ``AggTree`` — a
+segment tree of partial merges whose warm queries cost O(log S) node
+merges instead of the O(S) from-scratch reduction.
+``merge_streams(fleet, state, t)`` survives as a deprecated alias for
+``query_cohort(fleet, state, ALL, t)``.
 
 Registry::
 
@@ -530,43 +531,175 @@ def _make_swor(d: int, eps: float, window: int, *, ell: int | None = None,
 
 
 # ---------------------------------------------------------------------------
-# Multi-stream lifting (the serving-scale path)
+# The fleet lift (the serving-scale path)
 # ---------------------------------------------------------------------------
 
 
+def _require_jax(sk: SlidingSketch, who: str) -> None:
+    if sk.meta.get("backend") != "jax":
+        raise ValueError(
+            f"{who} requires a JAX-backed sketch, got {sk.name!r} "
+            f"(backend={sk.meta.get('backend')!r})")
+
+
 def vmap_streams(sk: SlidingSketch, streams: int) -> SlidingSketch:
-    """Lift a JAX-backed sketch to ``streams`` independent streams.
+    """Lift a JAX-backed sketch to ``streams`` independent streams in one
+    program on the default device (the lift with no placement).
 
     State leaves gain a leading ``(S, ...)`` axis; ``update`` takes
     ``(S, d)`` rows and ``(S,)`` timestamps; ``update_block`` takes
     ``(S, B, d)`` rows and ``(B,)`` or ``(S, B)`` timestamps and runs all
-    streams in **one fused XLA program** (one ``vmap`` over the jitted
-    block scan — this is how millions of per-user sketches are served).
-    ``query_rows`` / ``query`` broadcast a scalar query time across streams.
+    streams in **one fused XLA program**.  ``query_rows`` / ``query``
+    broadcast a scalar query time across streams.
     """
-    if sk.meta.get("backend") != "jax":
-        raise ValueError(
-            f"vmap_streams requires a JAX-backed sketch, got {sk.name!r} "
-            f"(backend={sk.meta.get('backend')!r})")
+    _require_jax(sk, "vmap_streams")
+    return _lift(sk, int(streams))
+
+
+def shard_streams(sk: SlidingSketch, streams: int, mesh=None, *,
+                  axis: str = "streams", topology=None) -> SlidingSketch:
+    """Lift a JAX-backed sketch to a device-sharded fleet of ``streams``.
+
+    Every device of ``mesh`` (default: a 1-D mesh over this process's
+    local devices) owns ``streams / n_devices`` per-user sketches and runs
+    the same vmapped block scan on them — one ``shard_map``'d SPMD program
+    per ``update_block``, no cross-device traffic on the update path
+    (streams are independent).  State leaves are sharded along their
+    leading ``(S, ...)`` stream axis; ``init`` returns the state already
+    placed.  Aggregate (cross-shard) queries go through
+    :func:`query_cohort`, whose upper tree-merge rounds are where the
+    collective traffic lives.
+
+    ``streams`` must be a multiple of the mesh axis size.
+
+    Multi-host: pass ``topology`` (a
+    :class:`repro.parallel.topology.FleetTopology`) and this process
+    holds only its OWN contiguous stream range — state leaves have
+    leading axis ``topology.local_size``, laid out over ``mesh``, and
+    ``update_block`` takes the local slab.  ``query_cohort`` still takes
+    GLOBAL cohorts and is a collective answered through a
+    :class:`~repro.parallel.topology.PartitionedAggTree` (owned subtrees
+    served locally, only the O(log S) top spine crossing processes as
+    compressed ``2ℓ×d`` node states, bit-identical to the unsplit
+    fleet).  Without a topology, a multi-process runtime is rejected
+    loudly — the implicit all-local-devices mesh would silently build a
+    fleet whose global shape no process actually holds.
+    """
+    _require_jax(sk, "shard_streams")
     S = int(streams)
+    if topology is not None and topology.S != S:
+        raise ValueError(
+            f"topology covers {topology.S} streams but shard_streams was "
+            f"asked for {S} — build both from the same fleet size")
+    if mesh is None:
+        if topology is None and jax.process_count() > 1:
+            raise ValueError(
+                f"shard_streams(streams={S}) in a multi-process "
+                f"runtime (process_count={jax.process_count()}) needs a "
+                "topology: the default mesh covers only this process's "
+                "local devices, so a global-shape fleet state would exist "
+                "on no process.  Pass topology=FleetTopology(streams) "
+                "(repro.parallel.topology) so each process owns a "
+                "contiguous stream range, or pass an explicit mesh if you "
+                "really mean a per-process private fleet.")
+        from repro.launch.mesh import make_local_mesh
+        mesh = make_local_mesh(axis)
+    return _lift(sk, S, mesh=mesh, axis=axis, topology=topology)
+
+
+def _lift(sk: SlidingSketch, S: int, *, mesh=None, axis: str = "streams",
+          topology=None) -> SlidingSketch:
+    """The one fleet lift behind :func:`vmap_streams` and
+    :func:`shard_streams`.
+
+    The placement is none (one program on the default device), a
+    ``mesh`` (the streams split over its ``axis`` devices) or a
+    ``topology`` over a mesh (this process's stream range only).  ``S``
+    is the fleet's global stream count; state leaves hold the streams of
+    this process.  ``update_block``, ``score`` and ``ranks`` become fleet
+    programs through one helper; ``update``, ``query_rows``, ``query``,
+    ``merge`` and ``space`` are plain ``vmap``s under every placement.
+    """
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    n = S if topology is None else int(topology.local_size)
+    meta = dict(sk.meta, streams=S, base=sk, agg_box={})
+    sharding = None
+    per_device = n
+    name = f"vmap[{sk.name}x{S}]"
+    if mesh is not None:
+        ndev = int(mesh.shape[axis])
+        if n % ndev:
+            raise ValueError(f"streams={n} must divide over {ndev} devices")
+        per_device = n // ndev
+        sharding = NamedSharding(mesh, P(axis))
+        meta.update(mesh=mesh, devices=ndev, axis=axis,
+                    slab_sharding=sharding)
+        name = f"shard[{sk.name}x{S}/{ndev}]"
+    if topology is not None:
+        lo, hi = topology.lo, topology.hi
+        meta.update(topology=topology, local_streams=n, local_range=(lo, hi))
+        name = f"topo[{sk.name}x{S}@{topology.pid}/{topology.P}:{lo}-{hi}]"
+
+    def program(fn, in_axes):
+        """``fn`` over one stream → its jitted fleet program: ``vmap``
+        over the streams, ``shard_map`` over the mesh axis when there is
+        one.  An argument of axis ``None`` is shared by every stream: it
+        is replicated over the mesh and broadcast per stream inside."""
+        v = jax.vmap(fn)
+
+        def lifted(*args):
+            return v(*(a if ax == 0
+                       else jnp.broadcast_to(a, (per_device,) + a.shape)
+                       for a, ax in zip(args, in_axes)))
+
+        lifted.__name__ = fn.__name__   # the program is named after fn
+        if mesh is not None:
+            lifted = jax.shard_map(
+                lifted, mesh=mesh, out_specs=P(axis), check_vma=False,
+                in_specs=tuple(P(axis) if ax == 0 else P() for ax in in_axes))
+        return jax.jit(lifted)
+
+    def place(rows):
+        """A host slab is placed along the streams; a device array (a
+        slab prefetched with ``meta["slab_sharding"]``) passes through
+        untouched, with no re-transfer."""
+        if sharding is None or isinstance(rows, jax.Array):
+            return rows
+        return jax.device_put(np.asarray(rows), sharding)
 
     def init(t0=1):
         one = sk.init(t0)
         return jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (S,) + jnp.shape(x)), one)
+            lambda x: jnp.broadcast_to(x, (n,) + jnp.shape(x)), one)
 
-    v_update = jax.vmap(sk.update)
-    v_block = jax.jit(jax.vmap(sk.update_block, in_axes=(0, 0, 0)))
+    if sharding is not None:
+        # built in place: each device materializes only its own stream
+        # shard (an eager init would first hold the whole fleet on one)
+        init = jax.jit(init, out_shardings=sharding)
 
-    def update(state, rows, ts):
-        ts = jnp.broadcast_to(jnp.asarray(ts, jnp.int32), (S,))
-        return v_update(state, rows, ts)
+    # shared (B,) tick stamps are replicated and broadcast per stream;
+    # per-stream (S, B) stamps are sharded with the streams
+    block_shared = program(sk.update_block, (0, 0, None))
+    block = program(sk.update_block, (0, 0, 0))
+
+    def pick(ts):
+        return block_shared if len(ts.shape) == 1 else block
 
     def update_block(state, rows, ts):
-        ts = jnp.asarray(ts, jnp.int32)
-        if ts.ndim == 1:
-            ts = jnp.broadcast_to(ts, (S, ts.shape[0]))
-        return v_block(state, rows, ts)
+        if not isinstance(ts, jax.Array):
+            ts = np.asarray(ts, np.int32)
+        return pick(ts)(state, place(rows), ts)
+
+    # lowers the update program from shapes, dtypes and shardings alone
+    update_block.lower = lambda state, rows, ts: pick(ts).lower(
+        state, rows, ts)
+
+    v_update = jax.vmap(sk.update)
+
+    def update(state, rows, ts):
+        ts = jnp.broadcast_to(jnp.asarray(ts, jnp.int32), (n,))
+        return v_update(state, rows, ts)
 
     def query_rows(state, t=None):
         return jax.vmap(lambda s: sk.query_rows(s, t))(state)
@@ -577,70 +710,47 @@ def vmap_streams(sk: SlidingSketch, streams: int) -> SlidingSketch:
     def merge(s1, s2, t=None):
         return jax.vmap(lambda a, b: sk.merge(a, b, t))(s1, s2)
 
-    # the fleet's query plane: one AggTree shared by every query_cohort
-    # call on this fleet (and by shard_streams fleets built on it), created
-    # lazily so fleets that never issue aggregate queries pay nothing
-    agg_box: Dict[str, Any] = {}
-
+    # the query plane: the fleet's tree, created lazily on first use
+    # (see agg_tree), so fleets that never issue aggregate queries pay
+    # nothing
     def query_cohort(state, cohort=ALL, t=None):
-        tree = agg_box.get("tree")
-        if tree is None:
-            tree = agg_box["tree"] = AggTree(sk, S)
-        return tree.query(state, cohort, t)
+        return agg_tree(fleet).query(state, cohort, t)
 
-    # the scoring plane lifts mechanically: the raw per-stream residual
-    # program rides on score._per_stream (see repro.sketch.score), so a
-    # whole (S, B, d) slab is scored in the same fused program shape as
-    # the block update — and the un-jitted vmapped programs are exposed
-    # for shard_streams to wrap in shard_map
-    raw = getattr(sk.score, "_per_stream", None)
-    v_ranks = jax.vmap(sk.ranks) if capability.has(sk, "ranks") else None
+    # the scoring plane: the raw per-stream residual program (tagged
+    # ``_per_stream`` by repro.sketch.score) scores a whole slab in the
+    # same program shape as the block update
     score = None
+    raw = getattr(sk.score, "_per_stream", None)
     if raw is not None:
         def scoped(s, x, t):
             with jax.named_scope("fleet.score"):
                 return raw(s, x, t)
 
-        v_raw_t = jax.vmap(scoped, in_axes=(0, 0, 0))
-        v_raw_nt = jax.vmap(lambda s, x: scoped(s, x, None))
-        j_raw_t = jax.jit(v_raw_t)
-        j_raw_nt = jax.jit(v_raw_nt)
+        score_t = program(scoped, (0, 0, None))
+        score_nt = program(lambda s, x: scoped(s, x, None), (0, 0))
 
         def score(state, rows, t=None):
-            rows = jnp.asarray(rows)
+            rows = place(rows)
             if t is None:
-                return j_raw_nt(state, rows)
-            ts = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (S,))
-            return j_raw_t(state, rows, ts)
+                return score_nt(state, rows)
+            return score_t(state, rows, jnp.asarray(t, jnp.int32))
 
-        score._vmapped_t = v_raw_t
-        score._vmapped_nt = v_raw_nt
-
-    ranks = None
-    if v_ranks is not None:
-        j_ranks = jax.jit(v_ranks)
-
-        def ranks(state):
-            return j_ranks(state)
-
-        ranks._vmapped = v_ranks
-
+    ranks = (program(sk.ranks, (0,)) if capability.has(sk, "ranks")
+             else None)
     v_space = jax.vmap(sk.space)
 
     def space(state):
         per = v_space(state)
-        tree = agg_box.get("tree")
+        tree = meta["agg_box"].get("tree")
         cache_rows = 0 if tree is None else tree.space()
         return FleetSpace(per_stream=per,
                           total=jnp.sum(per) + cache_rows,
                           cache_rows=cache_rows,
                           ranks=None if ranks is None else ranks(state))
 
-    fleet_name = f"vmap[{sk.name}x{S}]"
-
-    return capability.install_missing(SlidingSketch(
-        name=fleet_name,
-        meta=dict(sk.meta, streams=S, base=sk, agg_box=agg_box),
+    fleet = capability.install_missing(SlidingSketch(
+        name=name,
+        meta=meta,
         init=init,
         update=update,
         update_block=update_block,
@@ -652,6 +762,7 @@ def vmap_streams(sk: SlidingSketch, streams: int) -> SlidingSketch:
         score=score,
         ranks=ranks,
     ))
+    return fleet
 
 
 def query_cohort(fleet: SlidingSketch, state, cohort=ALL, t=None):
@@ -727,8 +838,8 @@ def merge_streams(fleet: SlidingSketch, state, t=None):
     state, but served from the fleet's cached :class:`AggTree` (repeated
     calls between ingests are near-free) instead of an O(S) re-reduction
     per call.  The uncached from-scratch reduction survives as
-    :func:`repro.sketch.query.full_reduce_streams` (the benchmark
-    baseline).  Kept for import compatibility; new code should call
+    :func:`repro.sketch.query.full_reduce_streams` (the uncached
+    reference).  Kept for import compatibility; new code should call
     :func:`query_cohort`.
     """
     import warnings
@@ -740,227 +851,6 @@ def merge_streams(fleet: SlidingSketch, state, t=None):
         "lives on as repro.sketch.query.full_reduce_streams",
         DeprecationWarning, stacklevel=2)
     return query_cohort(fleet, state, ALL, t)
-
-
-def shard_streams(sk: SlidingSketch, streams: int, mesh=None, *,
-                  axis: str = "streams", topology=None) -> SlidingSketch:
-    """Lift a JAX-backed sketch to a device-sharded fleet of ``streams``.
-
-    Built on :func:`vmap_streams`: every device of ``mesh`` (default: a 1-D
-    mesh over this process's local devices) owns ``streams / n_devices``
-    per-user sketches and runs the same vmapped block scan on them — one
-    ``shard_map``'d SPMD program per ``update_block``, no cross-device
-    traffic on the update path (streams are independent).  State leaves are
-    sharded along their leading ``(S, ...)`` stream axis; ``init`` returns
-    the state already placed.  Aggregate (cross-shard) queries go through
-    :func:`query_cohort`, whose upper tree-merge rounds are where the
-    collective traffic lives.
-
-    ``streams`` must be a multiple of the mesh axis size.
-
-    Multi-host: pass ``topology`` (a
-    :class:`repro.parallel.topology.FleetTopology`) and each process
-    builds the shard for its OWN contiguous stream range — state leaves
-    have leading axis ``topology.local_size``, laid out over that
-    process's local devices.  ``update_block`` takes the local slab;
-    ``query_cohort`` still takes GLOBAL cohorts and is a collective
-    answered through a
-    :class:`~repro.parallel.topology.PartitionedAggTree` (owned subtrees
-    served locally, only the O(log S) top spine crossing processes as
-    compressed ``2ℓ×d`` node states, bit-identical to the unsplit
-    fleet).  Without a topology, a multi-process runtime is rejected
-    loudly — the implicit all-local-devices mesh would silently build a
-    fleet whose global shape no process actually holds.
-    """
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    if sk.meta.get("backend") != "jax":
-        raise ValueError(
-            f"shard_streams requires a JAX-backed sketch, got {sk.name!r} "
-            f"(backend={sk.meta.get('backend')!r})")
-    if topology is not None:
-        return _shard_streams_topology(sk, int(streams), mesh, axis,
-                                       topology)
-    if mesh is None:
-        if jax.process_count() > 1:
-            raise ValueError(
-                f"shard_streams(streams={int(streams)}) in a multi-process "
-                f"runtime (process_count={jax.process_count()}) needs a "
-                "topology: the default mesh covers only this process's "
-                "local devices, so a global-shape fleet state would exist "
-                "on no process.  Pass topology=FleetTopology(streams) "
-                "(repro.parallel.topology) so each process owns a "
-                "contiguous stream range, or pass an explicit mesh if you "
-                "really mean a per-process private fleet.")
-        from repro.launch.mesh import make_local_mesh
-        mesh = make_local_mesh(axis)
-    ndev = int(mesh.shape[axis])
-    S = int(streams)
-    if S % ndev:
-        raise ValueError(f"streams={S} must divide over {ndev} devices")
-
-    fleet = vmap_streams(sk, S)                 # global-shape semantics
-    local = vmap_streams(sk, S // ndev)         # per-device program
-    spec = P(axis)
-    sharding = NamedSharding(mesh, spec)
-
-    # built in place: each device materializes only its own stream shard
-    # (an eager init would first hold the whole fleet on one device)
-    init = jax.jit(fleet.init, out_shardings=sharding)
-
-    def _block_program(ts_spec):
-        return jax.jit(jax.shard_map(
-            local.update_block, mesh=mesh,
-            in_specs=(spec, spec, ts_spec), out_specs=spec,
-            check_vma=False))
-
-    # shared (B,) tick stamps are replicated and broadcast per device;
-    # per-stream (S, B) stamps are sharded with the streams
-    shard_block_shared = _block_program(P())
-    shard_block = _block_program(spec)
-
-    def update_block(state, rows, ts):
-        if not isinstance(ts, jax.Array):
-            ts = np.asarray(ts, np.int32)
-        if not isinstance(rows, jax.Array):
-            # host slab: place it along the stream axis here, explicitly.
-            # An ingest pipeline that prefetched the slab with
-            # meta["slab_sharding"] skips this branch entirely — the
-            # already-placed device array flows into the jitted program
-            # with no re-transfer.
-            rows = jax.device_put(np.asarray(rows), sharding)
-        if ts.ndim == 1:
-            return shard_block_shared(state, rows, ts)
-        return shard_block(state, rows, ts)
-
-    def lower_update_block(state, rows, ts):
-        """Lower the update program for these arguments (shapes, dtypes
-        and shardings suffice; a host slab is placed along the streams)."""
-        prog = shard_block_shared if len(ts.shape) == 1 else shard_block
-        return prog.lower(state, rows, ts)
-
-    update_block.lower = lower_update_block
-
-    # scoring as one shard_map'd SPMD program per slab — each device runs
-    # the local fleet's vmapped residual program on its own stream shard,
-    # same layout contract as update_block (bit-identity with the vmap
-    # and per-stream paths is pinned in tests/sketch/test_score.py)
-    score = None
-    if capability.has(local, "score"):
-        shard_sc_t = jax.jit(jax.shard_map(
-            local.score._vmapped_t, mesh=mesh,
-            in_specs=(spec, spec, spec), out_specs=spec, check_vma=False))
-        shard_sc_nt = jax.jit(jax.shard_map(
-            local.score._vmapped_nt, mesh=mesh,
-            in_specs=(spec, spec), out_specs=spec, check_vma=False))
-
-        def score(state, rows, t=None):
-            if not isinstance(rows, jax.Array):
-                rows = jax.device_put(np.asarray(rows), sharding)
-            if t is None:
-                return shard_sc_nt(state, rows)
-            ts = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (S,))
-            return shard_sc_t(state, rows, ts)
-
-    ranks = None
-    if capability.has(local, "ranks"):
-        shard_ranks = jax.jit(jax.shard_map(
-            local.ranks._vmapped, mesh=mesh,
-            in_specs=(spec,), out_specs=spec, check_vma=False))
-
-        def ranks(state):
-            return shard_ranks(state)
-
-    def space(state):
-        fs = fleet.space(state)
-        return (fs if ranks is None
-                else fs._replace(ranks=ranks(state)))
-
-    return capability.install_missing(SlidingSketch(
-        name=f"shard[{sk.name}x{S}/{ndev}]",
-        meta=dict(sk.meta, streams=S, base=sk, mesh=mesh, devices=ndev,
-                  axis=axis, slab_sharding=sharding,
-                  agg_box=fleet.meta["agg_box"]),
-        init=init,
-        update=fleet.update,
-        update_block=update_block,
-        query_rows=fleet.query_rows,
-        query=fleet.query,
-        space=space,
-        merge=fleet.merge,
-        query_cohort=fleet.query_cohort,
-        score=score,
-        ranks=ranks,
-    ))
-
-
-def _shard_streams_topology(sk: SlidingSketch, S: int, mesh, axis: str,
-                            topology) -> SlidingSketch:
-    """The multi-host branch of :func:`shard_streams`: this process's
-    shard of a topology-partitioned fleet.
-
-    The local fleet is an ordinary single-host ``shard_streams`` over
-    ``topology.local_size`` streams (same SPMD update program, same slab
-    sharding contract) — only the *stream indexing* and the query plane
-    change: state/update/query operate on LOCAL shapes, while
-    ``query_cohort`` speaks GLOBAL stream ids through the collective
-    :class:`~repro.parallel.topology.PartitionedAggTree`.
-    """
-    from repro.parallel.topology import PartitionedAggTree
-
-    if topology.S != S:
-        raise ValueError(
-            f"topology covers {topology.S} streams but shard_streams was "
-            f"asked for {S} — build both from the same fleet size")
-    if mesh is None:
-        from repro.launch.mesh import make_local_mesh
-        mesh = make_local_mesh(axis)
-    local = shard_streams(sk, topology.local_size, mesh, axis=axis)
-
-    box: Dict[str, Any] = {}
-
-    def _tree() -> PartitionedAggTree:
-        tree = box.get("tree")
-        if tree is None:
-            tree = box["tree"] = PartitionedAggTree(sk, topology)
-        return tree
-
-    def query_cohort(state, cohort=ALL, t=None):
-        return _tree().query(state, cohort, t)
-
-    def space(state):
-        ls = local.space(state)
-        tree = box.get("tree")
-        cache_rows = 0 if tree is None else tree.space()
-        return FleetSpace(per_stream=ls.per_stream,
-                          total=jnp.sum(ls.per_stream) + cache_rows,
-                          cache_rows=cache_rows,
-                          ranks=ls.ranks)
-
-    # score/ranks operate on LOCAL shapes, like update/query — forwarded
-    # from the local shard fleet (already shard_map'd over this process's
-    # devices); only query_cohort speaks global stream ids
-    return capability.install_missing(SlidingSketch(
-        name=(f"topo[{sk.name}x{S}@{topology.pid}/{topology.P}"
-              f":{topology.lo}-{topology.hi}]"),
-        meta=dict(sk.meta, streams=S, base=sk, mesh=mesh,
-                  devices=local.meta["devices"], axis=axis,
-                  slab_sharding=local.meta["slab_sharding"],
-                  topology=topology,
-                  local_streams=topology.local_size,
-                  local_range=(topology.lo, topology.hi),
-                  agg_box=box),
-        init=local.init,
-        update=local.update,
-        update_block=local.update_block,
-        query_rows=local.query_rows,
-        query=local.query,
-        space=space,
-        merge=local.merge,
-        query_cohort=query_cohort,
-        score=(local.score if capability.has(local, "score") else None),
-        ranks=(local.ranks if capability.has(local, "ranks") else None),
-    ))
 
 
 # ---------------------------------------------------------------------------

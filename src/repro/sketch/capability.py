@@ -1,41 +1,31 @@
 """Capability protocol for the optional ``SlidingSketch`` surface.
 
-PRs 4 and 8 each grew the protocol by hand: ``query_cohort`` landed with a
-bespoke explanatory raiser in ``make_sketch`` plus a free-function guard,
-and ``query_interval`` repeated the pattern three more times (host
-baseline, JAX single sketch, history-less fleet) plus an ``install_*``
-mutation in ``history.py``.  Adding the scoring plane the same way would
-be a third divergent copy — so the pattern lives here, once:
-
 * a capability is an optional ``SlidingSketch`` field (``OPTIONAL_FIELDS``);
 * when a sketch lacks one, :func:`install_missing` fills the field with a
   *tagged raiser* whose message is derived from the sketch's actual
   context (:func:`context`) — single vs fleet, host vs JAX, history plane
   attached or not — so the guidance always names a constructor the caller
-  can really use (the PR-8 raisers told single-sketch users to call
-  ``install_query_interval(fleet, plane)`` with no fleet in sight);
+  can really use;
 * real implementations attach through :func:`install`, which tags the
   function and merges any meta the capability needs (e.g. the history
   plane's ``hist_box``);
 * :func:`capabilities` introspects the lot — name, availability, and the
-  would-be error text — uniformly for every variant, fleet lift, and
-  engine.
+  would-be error text — uniformly for every variant, fleet, and engine.
 
-Lifts (``vmap_streams`` / ``shard_streams``) call :func:`install_missing`
-on their product: raisers are regenerated for the *new* context (a fleet
-without a history plane explains how to attach one; a single sketch
-explains how to become a fleet first), while real implementations pass
-through untouched.
+The fleet lift (``vmap_streams`` / ``shard_streams``) calls
+:func:`install_missing` on its product: raisers are regenerated for the
+*new* context (a fleet without a history plane explains how to attach
+one; a single sketch explains how to become a fleet first), while real
+implementations pass through untouched.
 """
 
 from __future__ import annotations
 
 from typing import Any, Callable, Dict, NamedTuple, Optional
 
-#: The optional protocol fields, in declaration order.  ``query_cohort``
-#: and ``query_interval`` predate this module (PRs 4/8); ``score`` and
-#: ``ranks`` are the scoring plane (residual anomaly scores; per-stream
-#: adaptive rank).
+#: The optional protocol fields, in declaration order: the cohort and
+#: interval query planes, then the scoring plane (residual anomaly
+#: scores; per-stream adaptive rank).
 OPTIONAL_FIELDS = ("query_cohort", "query_interval", "score", "ranks")
 
 

@@ -559,9 +559,9 @@ def full_reduce_streams(fleet, state, t=None):
 
     This is the pre-query-plane ``merge_streams`` implementation —
     ⌈log₂S⌉ rounds of vmapped pairwise merges, an odd tail carried
-    pad-free at every round, no caching.  Kept as the benchmark baseline
-    (``benchmarks/fleet_throughput.py`` reports cached-tree speedup
-    against it) and as an O(S) merge path that allocates no cache.
+    pad-free at every round, no caching.  Kept as the reference the
+    cached tree is tested against and as an O(S) merge path that
+    allocates no cache.
     Answers differ from ``query_cohort(ALL)`` only in merge association
     order (both obey the additive FD bound).
     """

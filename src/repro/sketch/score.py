@@ -8,10 +8,11 @@ registered variant:
 
 * :func:`make_jax_score` wraps a raw ``(state, X, t) → (n,)`` scorer into
   the public ``score(state, X, t=None)`` — one jitted program per t-mode —
-  and tags the raw function on it (``_per_stream``) so ``vmap_streams`` /
-  ``shard_streams`` can lift scoring *mechanically* into the same fused /
-  SPMD programs that run the updates: a whole ``(S, B, d)`` slab is scored
-  in the tick that ingests it.
+  and tags the raw function on it (``_per_stream``).  The fleet lift
+  (``vmap_streams`` / ``shard_streams``) reads that tag and turns the raw
+  scorer into a fleet program the same way it turns the block update
+  into one: a whole ``(S, B, d)`` slab is scored in the tick that
+  ingests it.
 * :func:`make_host_score` is the numpy adapter for the host baselines
   (lmfd / difd / swr / swor): same residual against the orthonormal row
   space of whatever ``query()`` returns, computed with numpy SVD.

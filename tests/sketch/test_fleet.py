@@ -1,6 +1,7 @@
-"""Fleet scale-out: ``shard_streams`` must be a pure layout change — same
-per-stream states as sequential execution — and ``merge_streams`` must
-tree-reduce a fleet to one global-window sketch obeying the additive FD
+"""Fleet scale-out: every placement of the fleet lift (``vmap_streams``,
+``shard_streams`` over a mesh, and over a topology) must be a pure layout
+change — same per-stream states as sequential execution — and
+``merge_streams`` must tree-reduce a fleet to one global-window sketch obeying the additive FD
 bound.  The 2-fake-device SPMD path runs in a subprocess (XLA device count
 is fixed at import time); CI job 2 additionally runs this whole file under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=2``.
@@ -33,13 +34,37 @@ def _rel_err(AW, B):
     return float(np.linalg.norm(M, 2) / np.sum(AW * AW))
 
 
-def test_shard_streams_matches_sequential_reference():
-    S, n, d, N = 8, 64, 8, 24
+def _place(sk, S, placement):
+    if placement == "none":
+        return vmap_streams(sk, S)
+    if placement == "mesh":                   # whatever devices exist
+        return shard_streams(sk, S)
+    from repro.parallel.topology import FleetTopology
+    return shard_streams(sk, S, topology=FleetTopology(
+        S, num_processes=1, process_id=0))
+
+
+@pytest.mark.parametrize("name", ["dsfd", "seq-dsfd", "time-dsfd"])
+@pytest.mark.parametrize("placement", ["none", "mesh", "topology"])
+def test_shard_streams_matches_sequential_reference(placement, name):
+    """Every placement of the one fleet lift is a pure layout change: each
+    stream's rows and space equal that stream's run alone through the
+    base sketch."""
+    S, n, d, N, R = 8, 64, 8, 24, 4.0
     X = _streams(S, n, d)
+    hyper = {}
+    if name != "dsfd":
+        # the layered variants take ‖a‖² ∈ [1, R]; unit rows would put
+        # every window energy exactly on a swap threshold, where one ulp
+        # of summation order decides the swap
+        hyper = {"R": R}
+        norms = np.random.default_rng(4).uniform(1.0, R, size=(S, n, 1))
+        X = (X * np.sqrt(norms)).astype(np.float32)
     ts = jnp.arange(1, n + 1, dtype=jnp.int32)
-    sk = make_sketch("dsfd", d=d, eps=1 / 4, window=N)
-    sh = shard_streams(sk, S)                 # whatever devices exist
-    assert sh.meta["devices"] == jax.device_count()
+    sk = make_sketch(name, d=d, eps=1 / 4, window=N, **hyper)
+    sh = _place(sk, S, placement)
+    if placement != "none":
+        assert sh.meta["devices"] == jax.device_count()
     state = sh.update_block(sh.init(), jnp.asarray(X), ts)
     rows_v = np.asarray(sh.query_rows(state, n))
     fs = sh.space(state)                      # FleetSpace: per-stream + total

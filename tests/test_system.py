@@ -1,6 +1,5 @@
 """End-to-end system tests: train loop (loss ↓), checkpoint/elastic
-restart, DS-FD training integrations, serving engine, data pipeline
-determinism."""
+restart, DS-FD training integrations, data pipeline determinism."""
 
 import os
 
@@ -12,8 +11,6 @@ import pytest
 from repro.configs.base import get_config
 from repro.data.tokens import TokenPipeline
 from repro.launch.mesh import make_mesh
-from repro.models import api
-from repro.models.params import init_params
 from repro.train import checkpoint as ckpt
 from repro.train.loop import LoopConfig, train
 from repro.train.train_step import TrainStepConfig
@@ -92,25 +89,6 @@ def test_sketchy_optimizer_trains(tiny_cfg):
     losses = [h["loss"] for h in res["history"]]
     assert np.isfinite(losses).all()
     assert np.mean(losses[-4:]) < np.mean(losses[:4])
-
-
-def test_serve_engine_continuous_batching(tiny_cfg):
-    from repro.serve.engine import EngineConfig, Request, ServeEngine
-    params = init_params(api.param_defs(tiny_cfg), jax.random.PRNGKey(0))
-    eng = ServeEngine(tiny_cfg, params,
-                      EngineConfig(slots=2, s_max=64,
-                                   prefill_buckets=(16,)))
-    rng = np.random.default_rng(0)
-    for uid in range(5):
-        eng.submit(Request(uid=uid,
-                           prompt=rng.integers(
-                               0, tiny_cfg.vocab, 8).astype(np.int32),
-                           max_new=6))
-    done = eng.run(max_ticks=200)
-    assert len(done) == 5
-    for r in done.values():
-        assert len(r.out_tokens) == 7          # prefill token + 6 decoded
-        assert all(0 <= t < tiny_cfg.vocab for t in r.out_tokens)
 
 
 def test_token_pipeline_deterministic_and_shardable():
